@@ -489,8 +489,8 @@ class IoCtx:
     # -- reads ---------------------------------------------------------
 
     def read(self, oid: str, length: int = 0, offset: int = 0,
-             snap: int | None = None) -> bytes:
-        data = self._op(oid, [("read", offset, length)],
+             snap: int | None = None, timeout: float = 30.0) -> bytes:
+        data = self._op(oid, [("read", offset, length)], timeout=timeout,
                         snap_override=snap)
         return bytes(data) if data is not None else b""
 

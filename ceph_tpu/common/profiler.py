@@ -282,15 +282,10 @@ PROFILER = DeviceProfiler()
 
 def profiled_jit(kernel: str, fn=None, **jit_kwargs):
     """`jax.jit` with registry accounting: profiled_jit("name", fn)
-    or @profiled_jit("name", static_argnames=...).  Falls back to the
-    bare function when jax is unavailable (host-only environments)."""
+    or @profiled_jit("name", static_argnames=...)."""
     def apply(f):
-        try:
-            import jax
-            jitted = jax.jit(f, **jit_kwargs)
-        except Exception:
-            jitted = f
-        return PROFILER.wrap_jit(kernel, jitted)
+        import jax
+        return PROFILER.wrap_jit(kernel, jax.jit(f, **jit_kwargs))
     if fn is None:
         return apply
     return apply(fn)

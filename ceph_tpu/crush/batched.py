@@ -35,18 +35,6 @@ from .map import (CRUSH_ITEM_NONE, CRUSH_ITEM_UNDEF, CrushMap,
 S64_MIN = -(1 << 63)
 
 
-def _enable_x64():
-    """`jax.enable_x64()` with a fallback to the jax.experimental spelling
-    (the top-level alias comes and goes across jax releases; without the
-    shim the whole device CRUSH path dies on AttributeError)."""
-    import jax
-    try:
-        return jax.enable_x64()
-    except AttributeError:
-        from jax.experimental import enable_x64
-        return enable_x64()
-
-
 @dataclass(frozen=True)
 class CompiledMap:
     """Dense array form of a straw2 CrushMap for device execution."""
@@ -517,8 +505,7 @@ def batched_do_rule(cmap: CrushMap, ruleno: int, xs, result_max: int,
 
     device_out: return the device array WITHOUT the device->host copy
     (the caller pulls results when it wants them — benchmarks time the
-    device sweep itself, and on some transports a d2h mid-run degrades
-    the session).
+    device sweep itself).
 
     choose_args: weight-set/ids substitution — an arg map dict
     (bucket_id -> {"ids", "weight_set"}) or an int selecting one of
@@ -540,8 +527,7 @@ def batched_do_rule(cmap: CrushMap, ruleno: int, xs, result_max: int,
 
     shape = _rule_shape(cmap, ruleno)
     # a device-resident seed array stays on device: np.asarray would
-    # silently d2h it (and on some transports one d2h degrades the
-    # session) — the device path consumes it directly
+    # silently d2h it — the device path consumes it directly
     xs_is_dev = type(xs).__module__.startswith("jax")
     if not xs_is_dev:
         xs = np.asarray(xs)
@@ -618,7 +604,7 @@ def batched_do_rule(cmap: CrushMap, ruleno: int, xs, result_max: int,
         kernel = _indep_kernel(cm, out_size, numrep, shape["type"],
                                chooseleaf, tries, recurse_tries,
                                placement)
-    with _enable_x64():
+    with jax.enable_x64(True):
         xs_dev = jnp.asarray(xs, dtype=jnp.int64)
         if xs_sharding is not None:
             xs_dev = jax.device_put(xs_dev, xs_sharding)
@@ -636,7 +622,7 @@ def batched_do_rule(cmap: CrushMap, ruleno: int, xs, result_max: int,
         out = kernel(*tables, xs_dev, wvec, -1 - shape["root"])
     if device_out:
         if out.shape[1] < result_max:
-            with _enable_x64():
+            with jax.enable_x64(True):
                 out = jnp.pad(out,
                               ((0, 0), (0, result_max - out.shape[1])),
                               constant_values=CRUSH_ITEM_NONE)

@@ -39,8 +39,8 @@ _bank_pick_fn = None
 def _bank_pick(bank, i: int):
     """Device-side bank row select with the index TRACED (one compiled
     gather serves every signature). A static `bank[i]` would bake each
-    distinct index into its own tiny executable — harmless locally, but
-    each fresh compile costs an RTT-scale stall on a remote transport."""
+    distinct index into its own tiny executable, and each fresh
+    compile stalls the decode that needed it."""
     global _bank_pick_fn
     if _bank_pick_fn is None:
         import jax
@@ -200,9 +200,8 @@ class GeneratorCodec(ErasureCode):
         """Build the device-resident decode-matrix BANK: every C(n,k)
         erasure signature's decode bitmatrix, stacked and uploaded in
         ONE transfer. A cache miss then costs a device-side slice
-        instead of a host matrix build + per-miss H2D (which over a
-        congested transport costs an RTT per fresh signature — measured
-        2000x the decode itself). The reference's ISA table cache
+        instead of a host matrix build + per-miss H2D per fresh
+        signature. The reference's ISA table cache
         (ErasureCodeIsaTableCache.cc) builds tables lazily per miss
         because the CPU consumes them in place; on an accelerator the
         bank trade (~1 MB resident for k=8,m=3) is the right one."""

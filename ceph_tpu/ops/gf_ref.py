@@ -1,7 +1,7 @@
 """Exact numpy reference implementations of the codec math (the oracle).
 
 Everything the TPU kernels produce must be bit-identical to these functions
-(BASELINE.md correctness gate: "jax_tpu output bit-identical to the CPU
+(BASELINE.json correctness gate: "jax_tpu output bit-identical to the CPU
 reference implementation for the same profile"). They are deliberately
 simple and unoptimized.
 

@@ -96,8 +96,7 @@ def matrix_encode_multi(bitmats: jax.Array, data: jax.Array,
     decode matrix per erasure signature). data: [P, ..., k, N].
     Returns [P, ..., m, N]. This is the cross-op coalescing primitive:
     P concurrent OSD ops (each its own generator or decode matrix)
-    become one dispatch — on a remote transport that collapses P
-    round-trips into one, and on-device the lanes fill the MXU batch
+    become one dispatch, and on-device the lanes fill the MXU batch
     dimension."""
     return jax.vmap(lambda bm, d: matrix_encode(bm, d, w))(bitmats, data)
 

@@ -14,9 +14,10 @@ program over the staged [S, k, chunk] batch:
 
 One h2d of raw data, one fused program, one d2h of parity + digests +
 compressed payload. The CRC machinery is a GF(2)-linear tree combine:
-per-byte table CRCs are folded pairwise with precomputed 32x32 "append
-2^l zero bytes" matrices (M_{2h} = M_h . M_h), so the whole digest is
-O(log L) vectorized levels instead of a byte-serial loop. Dynamic
+per-byte table CRCs are folded 128 lanes at a time, each lane shifted
+past its successors by a precomputed 32x32 "append n zero bytes"
+matrix, so the whole digest is O(log L) vectorized levels instead of a
+byte-serial loop, in a lane-major layout the TPU tiles densely. Dynamic
 stored lengths (the compressed prefix) are handled by UN-shifting the
 full-capacity CRC with inverse matrices selected by the pad's bits —
 valid because the stored buffer is zero beyond the stored prefix and
@@ -57,6 +58,7 @@ _BLOCK = 64
 _POLY_ZLIB = 0xEDB88320   # reflected crc32 (zlib/HashInfo/deep-scrub)
 _POLY_C = 0x82F63B78      # reflected crc32c (Castagnoli)
 _LEVELS = 31              # shift matrices for appends up to 2^30 bytes
+_LANES = 128              # combine width per CRC tree level
 
 
 def _crc_table(poly: int) -> np.ndarray:
@@ -121,6 +123,7 @@ class _PolyConsts:
             shifts.append(_mat_mul(shifts[-1], shifts[-1]))
         self.shift = np.stack(shifts)              # [.., 32]: append 2^l B
         self.inv = np.stack([_mat_inv(s) for s in shifts])
+        self.lanes: dict = {}                      # (seg, q) -> [32, q]
 
     def _zero_byte_update(self, state: int) -> int:
         return (state >> 8) ^ int(self.table[state & 0xFF])
@@ -134,6 +137,23 @@ class _PolyConsts:
             nbytes >>= 1
             lvl += 1
         return state
+
+    def lane_mats(self, seg: int, q: int) -> np.ndarray:
+        """[32, q] column masks for one combine level: lane j holds the
+        crc of the j-th of q consecutive seg-byte segments, and is
+        shifted past the (q - 1 - j) segments that follow it."""
+        with _CONSTS_LOCK:
+            mats = self.lanes.get((seg, q))
+            if mats is None:
+                base = np.array([self.shift_n(1 << j, seg)
+                                 for j in range(32)], dtype=np.uint32)
+                powers = [np.array([1 << j for j in range(32)],
+                                   dtype=np.uint32)]       # M^0 = I
+                for _ in range(q - 1):
+                    powers.append(_mat_mul(base, powers[-1]))
+                mats = self.lanes.setdefault(
+                    (seg, q), np.stack(powers[::-1], axis=1))
+            return mats
 
 
 _CONSTS: dict = {}
@@ -283,10 +303,7 @@ def bitplane_decompress(buf, padded_len: int) -> bytes:
 
 def fused_supported(codec) -> bool:
     """Only element-layout matrix codecs on the jax backend fuse."""
-    try:
-        from ..models.matrix_base import MatrixErasureCode
-    except Exception:
-        return False
+    from ..models.matrix_base import MatrixErasureCode
     return (isinstance(codec, MatrixErasureCode)
             and getattr(codec, "backend", "") == "jax"
             and getattr(codec, "_bitmat", None) is not None)
@@ -329,52 +346,67 @@ def _dev_consts(device=None):
         if ent is None:
             z, c = _poly_consts(_POLY_ZLIB), _poly_consts(_POLY_C)
             arrs = tuple(jnp.asarray(a) for a in
-                         (z.table, z.shift, z.inv, c.table, c.shift))
+                         (z.table, z.inv, c.table))
             if device is not None:
                 arrs = tuple(jax.device_put(a, device) for a in arrs)
             ent = cache.setdefault(key, arrs)
     return ent
 
 
-def _xor_fold(x):
-    # XOR-reduce the trailing axis (power-of-two width)
-    while x.shape[-1] > 1:
-        x = x[..., 0::2] ^ x[..., 1::2]
-    return x[..., 0]
-
-
 def _mat_apply_dev(cols, x):
-    """cols: [32] uint32 column masks; x: [...] uint32 -> M.x"""
+    """cols: [32] uint32 column masks (or [32, q], one matrix per lane
+    of x's trailing axis); x: [...] uint32 -> M.x
+
+    One select-and-xor per bit, all elementwise: XLA fuses the 32 steps
+    into a single pass, so no [..., 32] intermediate is materialized
+    (that expansion was ~800x the input in temporaries)."""
     import jax.numpy as jnp
-    bits = (x[..., None] >> jnp.arange(32, dtype=jnp.uint32)) \
-        & jnp.uint32(1)
-    return _xor_fold(jnp.where(bits.astype(bool), cols, jnp.uint32(0)))
+    out = jnp.zeros_like(x)
+    for j in range(32):
+        bit = ((x >> jnp.uint32(j)) & jnp.uint32(1)).astype(bool)
+        out = out ^ jnp.where(bit, cols[j], jnp.uint32(0))
+    return out
 
 
-def _crc_raw_tree(streams, table, shift):
-    """crc_raw (init 0, no xor-out) of each row of streams [..., L]
-    via per-byte table CRCs + log2(L) pairwise combine levels."""
+def _combine_lanes(x, mats):
+    """x: [..., q] uint32 crc_raw of q consecutive equal-length
+    segments; mats: [32, q] (lane_mats). Returns [...]: crc_raw of
+    their concatenation (each lane shifted past its successors, then
+    XOR-reduced across the lane axis)."""
+    import jax
     import jax.numpy as jnp
-    L = streams.shape[-1]
-    L2 = _next_pow2(max(L, 1))
+    out = _mat_apply_dev(mats, x)
+    return jax.lax.reduce(out, jnp.uint32(0), jax.lax.bitwise_xor,
+                          (out.ndim - 1,))
+
+
+def _crc_raw_tree(streams, table, pc):
+    """crc_raw (init 0, no xor-out) of each row of streams [..., L]:
+    per-byte table CRCs, then log_128(L) combine levels that each fold
+    128-lane rows of consecutive segments (lane-major, so the device
+    layout stays dense)."""
+    import jax.numpy as jnp
     v = table[streams.astype(jnp.int32)]
-    if L2 != L:
-        pad = jnp.zeros(streams.shape[:-1] + (L2 - L,), dtype=jnp.uint32)
-        v = jnp.concatenate([pad, v], axis=-1)   # front zeros: crc_raw no-op
-    lvl = 0
+    seg = 1
     while v.shape[-1] > 1:
         n = v.shape[-1]
-        pairs = v.reshape(v.shape[:-1] + (n // 2, 2))
-        v = _mat_apply_dev(shift[lvl], pairs[..., 0]) ^ pairs[..., 1]
-        lvl += 1
+        q = min(_LANES, n)
+        p = -(-n // q)
+        if p * q != n:
+            # front zeros are a crc_raw no-op at every level
+            pad = jnp.zeros(v.shape[:-1] + (p * q - n,), dtype=jnp.uint32)
+            v = jnp.concatenate([pad, v], axis=-1)
+        v = _combine_lanes(v.reshape(v.shape[:-1] + (p, q)),
+                           jnp.asarray(pc.lane_mats(seg, q)))
+        seg *= q
     return v[..., 0]
 
 
-def _crc32_full(streams, table, shift, init_const):
+def _crc32_full(streams, table, pc, init_const):
     """Standard crc32 (init 0xFFFFFFFF, xor-out) of static-length rows.
     init_const = shift_L(0xFFFFFFFF), host-precomputed for the static L."""
     import jax.numpy as jnp
-    return _crc_raw_tree(streams, table, shift) ^ init_const \
+    return _crc_raw_tree(streams, table, pc) ^ init_const \
         ^ jnp.uint32(0xFFFFFFFF)
 
 
@@ -490,16 +522,17 @@ def _build_program(donate: bool):
         static_argnames=("w", "mode", "required_milli",
                          "entropy_max_milli", "cap2", "stripe_width"),
         donate_argnums=(0,) if donate else ())
-    def program(data, bitmat, tab_z, sh_z, inv_z, tab_c, sh_c,
+    def program(data, bitmat, tab_z, inv_z, tab_c,
                 init_chunk_c, init_shard_z, *, w, mode, required_milli,
                 entropy_max_milli, cap2, stripe_width):
         import jax.numpy as jnp
         S, k, chunk = data.shape
         N = S * k * chunk
         flat = data.reshape(N)
+        pz = _poly_consts(_POLY_ZLIB)
         # (a) per-chunk digests of the RAW chunks
         rows = data.reshape(S * k, chunk)
-        chunk_crc32c = _crc32_full(rows, tab_c, sh_c,
+        chunk_crc32c = _crc32_full(rows, tab_c, _poly_consts(_POLY_C),
                                    init_chunk_c).reshape(S, k)
         chunk_xxh32 = _xxh32_dev(rows).reshape(S, k)
         if mode == "store":
@@ -507,7 +540,7 @@ def _build_program(donate: bool):
             all_rows = jnp.concatenate([data, parity], axis=1)
             streams = jnp.swapaxes(all_rows, 0, 1).reshape(
                 all_rows.shape[1], S * chunk)
-            shard_crcs = _crc32_full(streams, tab_z, sh_z, init_shard_z)
+            shard_crcs = _crc32_full(streams, tab_z, pz, init_shard_z)
             return {"parity": parity, "shard_crcs": shard_crcs,
                     "chunk_crc32c": chunk_crc32c,
                     "chunk_xxh32": chunk_xxh32}
@@ -545,7 +578,7 @@ def _build_program(donate: bool):
             // jnp.int32(stripe_width)
         pad_bytes = ((jnp.int32(S_cap) - used)
                      * jnp.int32(chunk)).astype(jnp.uint32)
-        reg = _crc_raw_tree(streams, tab_z, sh_z) ^ init_shard_z
+        reg = _crc_raw_tree(streams, tab_z, pz) ^ init_shard_z
         shard_crcs = _crc_unshift(reg, inv_z, pad_bytes) \
             ^ jnp.uint32(0xFFFFFFFF)
         return {"parity": parity, "stored": stored,
@@ -582,14 +615,10 @@ def device_crc32(data, device=None) -> int:
     the primary still READS the on-disk shard bytes (silent disk
     bitrot must stay catchable — the write-time digest only says what
     the bytes SHOULD be), but the hash itself runs on device, so the
-    host never walks a crc loop.  Host zlib fallback without jax."""
+    host never walks a crc loop."""
+    import jax
+    import jax.numpy as jnp
     buf = bytes(data)
-    try:
-        import jax
-        import jax.numpy as jnp
-    except Exception:
-        import zlib
-        return zlib.crc32(buf) & 0xFFFFFFFF
     z = _poly_consts(_POLY_ZLIB)
     L = len(buf)
     L2 = _next_pow2(max(L, 1))
@@ -604,10 +633,10 @@ def device_crc32(data, device=None) -> int:
         cache = _CONSTS.setdefault("scrub_jit", {})
         fn = cache.get(key)
     if fn is None:
-        tab_z, sh_z = _dev_consts(device)[0:2]
+        tab_z = _dev_consts(device)[0]
 
-        def crc_fn(stream, init_c, _t=tab_z, _s=sh_z):
-            return _crc_raw_tree(stream[None, :], _t, _s)[0] \
+        def crc_fn(stream, init_c, _t=tab_z, _pc=z):
+            return _crc_raw_tree(stream[None, :], _t, _pc)[0] \
                 ^ init_c ^ jnp.uint32(0xFFFFFFFF)
 
         from ..common.profiler import PROFILER
@@ -644,7 +673,7 @@ def run_fused(codec, batch, mode: str = "store",
     sw = k * chunk
     N = S * k * chunk
     z, c = _poly_consts(_POLY_ZLIB), _poly_consts(_POLY_C)
-    tab_z, sh_z, inv_z, tab_c, sh_c = _dev_consts(device)
+    tab_z, inv_z, tab_c = _dev_consts(device)
     bitmat = codec._device_bitmat(device) if device is not None \
         else codec._device_bitmat()
     init_chunk_c = np.uint32(c.shift_n(0xFFFFFFFF, chunk))
@@ -660,7 +689,7 @@ def run_fused(codec, batch, mode: str = "store",
     if device is not None and data_dev is None:
         data = jax.device_put(data, device)
     return fused_program(donate)(
-        data, bitmat, tab_z, sh_z, inv_z, tab_c, sh_c,
+        data, bitmat, tab_z, inv_z, tab_c,
         jnp.uint32(init_chunk_c), jnp.uint32(init_shard_z),
         w=w, mode=mode, required_milli=int(required_ratio * 1000),
         entropy_max_milli=int(entropy_max_bits * 1000), cap2=cap2,

@@ -95,6 +95,8 @@ class OSDDaemon(Dispatcher):
         self.mon_client.map_max_advance = \
             conf.get_val("osd_map_max_advance")
         self.osdmap = OSDMap()
+        # client ops stamped with a map epoch we have not seen yet
+        self._waiting_for_map: list = []
         self.pgs: dict = {}
         # (session, tid) -> None (executing) | (result, data)
         from ..common.bounded import BoundedDict
@@ -215,11 +217,8 @@ class OSDDaemon(Dispatcher):
         # pipeline and the HBM chunk tier both pin to it, so N
         # daemons land one-per-chip with no global device lock
         from ..parallel.placement import PLACEMENT
-        try:
-            self.home_device = PLACEMENT.resolve(
-                whoami, conf.get_val("osd_device_index"))
-        except Exception:
-            self.home_device = None
+        self.home_device = PLACEMENT.resolve(
+            whoami, conf.get_val("osd_device_index"))
         # rateless mesh dispatch (parallel/rateless.py, direction J):
         # honour the conf gate so a daemon started with
         # osd_mesh_rateless=false never pulls the process-global
@@ -610,10 +609,17 @@ class OSDDaemon(Dispatcher):
         with self.lock:
             self.osdmap = newmap
             pgs = list(self.pgs.values())
+            ready = [m for m in self._waiting_for_map
+                     if m.map_epoch <= newmap.epoch]
+            self._waiting_for_map = [m for m in self._waiting_for_map
+                                     if m.map_epoch > newmap.epoch]
         self._apply_pool_qos(newmap)
         for pg in pgs:
             self.op_wq.queue(pg.pgid, pg.on_map_change)
         self._scan_for_new_pgs()
+        # ops that waited for this map queue behind its PG map changes
+        for msg in ready:
+            self._enqueue_client_op(msg)
 
     def _apply_pool_qos(self, m) -> None:
         """Push pool dmclock profiles from the osdmap into every op
@@ -812,6 +818,11 @@ class OSDDaemon(Dispatcher):
         grace = conf.get_val("osd_heartbeat_grace")
         peers = [o for o in self.osdmap.get_up_osds()
                  if o != self.whoami]
+        # an unacked stamp of a peer the map shows down belongs to its
+        # dead incarnation: kept, it would report the restarted peer
+        # failed on its first tick back up and flap it down again
+        for osd in set(self.hb_pending) - set(peers):
+            self.hb_pending.pop(osd, None)
         for osd in peers:
             addr = self._osd_addr(osd, "hb")
             if addr is None:
@@ -1391,6 +1402,14 @@ class OSDDaemon(Dispatcher):
         return None
 
     def _enqueue_client_op(self, msg) -> None:
+        # an op stamped with a newer map than ours waits for that map
+        # (the reference's require_same_or_newer_map): a write sent
+        # right after a pool snapshot, or a read retargeted to a new
+        # primary, must not run against the map before the change
+        with self.lock:
+            if getattr(msg, "map_epoch", 0) > self.osdmap.epoch:
+                self._waiting_for_map.append(msg)
+                return
         denial = self._check_op_caps(msg)
         if denial is not None:
             import errno as _errno

@@ -26,6 +26,9 @@ vectorized on host.
 from __future__ import annotations
 
 import copy
+import hashlib
+import threading
+from collections import OrderedDict
 from dataclasses import dataclass, field
 
 import numpy as np
@@ -37,6 +40,57 @@ from ..crush.mapper_ref import crush_do_rule
 
 __all__ = ["PGID", "PGPool", "OSDMap", "Incremental", "OSDMapMapping",
            "POOL_TYPE_REPLICATED", "POOL_TYPE_ERASURE", "CRUSH_ITEM_NONE"]
+
+
+def crush_fingerprint(cmap: CrushMap) -> bytes:
+    """Digest of everything a CRUSH mapping reads: buckets, rules,
+    tunables and choose_args. Equal digests map every input alike."""
+    h = hashlib.blake2b(digest_size=16)
+    for bid in sorted(cmap.buckets):
+        b = cmap.buckets[bid]
+        h.update(repr((bid, b.alg, b.type, b.hash)).encode())
+        h.update(np.ascontiguousarray(b.items, dtype=np.int64).tobytes())
+        h.update(np.ascontiguousarray(b.weights, dtype=np.int64).tobytes())
+    h.update(repr([r.steps for r in cmap.rules]).encode())
+    h.update(repr(vars(cmap.tunables)).encode())
+    for sid in sorted(cmap.choose_args):
+        for bid, arg in sorted(cmap.choose_args[sid].items()):
+            h.update(repr((sid, bid)).encode())
+            for key in ("ids", "weight_set"):
+                v = (arg or {}).get(key)
+                h.update(b"-" if v is None
+                         else np.asarray(v, dtype=np.int64).tobytes())
+    return h.digest()
+
+
+class _RawMappingCache:
+    """Process-wide memo of raw CRUSH mappings. A mapping is a pure
+    function of (crush content, rule, input, size, reweights, choose_args
+    set), so it holds across map epochs that change none of them (an OSD
+    going down or up, a pool snapshot) and across every daemon sharing
+    the process — each of which would otherwise rerun the scalar
+    mapper for every PG on every epoch."""
+
+    def __init__(self, max_entries: int = 1 << 16):
+        self._lock = threading.Lock()
+        self._rows: OrderedDict = OrderedDict()
+        self._max = max_entries
+
+    def get(self, key):
+        with self._lock:
+            row = self._rows.get(key)
+            if row is not None:
+                self._rows.move_to_end(key)
+            return row
+
+    def put(self, key, row: tuple) -> None:
+        with self._lock:
+            self._rows[key] = row
+            if len(self._rows) > self._max:
+                self._rows.popitem(last=False)
+
+
+_RAW_MAPPINGS = _RawMappingCache()
 
 DEFAULT_PRIMARY_AFFINITY = 0x10000
 MAX_PRIMARY_AFFINITY = 0x10000
@@ -322,9 +376,16 @@ class OSDMap:
             # default set (OSDMap.cc passes the pool id as the
             # choose_args index; the balancer writes per-pool or
             # default weight-sets)
-            osds = crush_do_rule(self.crush, ruleno, pps, pool.size,
-                                 self._weight_vector(),
-                                 choose_args=pgid.pool)
+            weights = self._weight_vector()
+            key = (crush_fingerprint(self.crush), ruleno, pps, pool.size,
+                   weights.tobytes(), pgid.pool)
+            row = _RAW_MAPPINGS.get(key)
+            if row is None:
+                row = tuple(crush_do_rule(self.crush, ruleno, pps,
+                                          pool.size, weights,
+                                          choose_args=pgid.pool))
+                _RAW_MAPPINGS.put(key, row)
+            osds = list(row)
         self._remove_nonexistent_osds(pool, osds)
         return osds, pps
 

@@ -1155,12 +1155,16 @@ class PG:
                 finish(-2, None)   # pre-read source vanished
                 return
 
-            def on_data(data, roid=roid, i=i):
-                if data is None:
-                    # degraded below k / reconstruction failed: b""
-                    # here would snapshot or roll back to EMPTY content
-                    # and ack it. Usually TRANSIENT (shard mid-recovery
-                    # excluded from reads): retry briefly, then error
+            def on_data(data, roid=roid, i=i, size=size):
+                if data is None or len(data) != size:
+                    # degraded below k / reconstruction failed, or a
+                    # short read from a stale cached hinfo: capturing
+                    # it would snapshot or roll back to the WRONG
+                    # content and ack it. Usually TRANSIENT (shard
+                    # mid-recovery excluded from reads; a new primary
+                    # whose cached hinfo predates the write): drop the
+                    # cached hinfo, retry briefly, then error
+                    self.backend.hinfo_cache.pop(roid, None)
                     if attempt < 10:
                         self.daemon.timer.add_event_after(
                             0.5, read_next, i, attempt + 1)

@@ -8,8 +8,7 @@ transaction); under concurrency each op would pay its own device
 dispatch. Stripes are embarrassingly parallel, so concurrent ops that
 share a generator (same pool/codec) or a decode matrix (same erasure
 signature) CONCATENATE along the stripe axis and ride ONE device
-program — N dispatches become ceil(N / max_batch), and on a remote
-transport N round-trips collapse the same way.
+program — N dispatches become ceil(N / max_batch).
 
 The dispatcher is an overlapped depth-N pipeline (ROADMAP direction A:
 the TPU historically spent >99% of streaming wall-clock waiting on the
@@ -153,22 +152,6 @@ class _JaxDevOps:
             # single d2h)
             import jax
             return jax.device_get(out)
-        return np.asarray(out)
-
-
-class _HostDevOps:
-    """No-jax fallback: the stages degenerate to a plain call (the
-    fake-device tests substitute their own instrumented ops here)."""
-
-    def h2d(self, host):
-        return host
-
-    def run(self, fn, x):
-        return fn(x)
-
-    def d2h(self, out):
-        if isinstance(out, dict):
-            return {k: np.asarray(v) for k, v in out.items()}
         return np.asarray(out)
 
 
@@ -346,9 +329,7 @@ class TpuDispatcher:
         self._lat_ewma: float | None = None
         self._lat_alpha = 0.25
         # device leg implementations (tests substitute a fake here)
-        self._jax = self._probe_jax()
-        self._devops = _JaxDevOps(self.device) if self._jax \
-            else _HostDevOps()
+        self._devops = _JaxDevOps(self.device)
         self._donate_fns: dict = {}   # key -> jitted donating fn | False
         self._donate_ok = self._probe_donation()
         # fused write-transform ledger (dispatch_status "fused" section)
@@ -381,28 +362,15 @@ class TpuDispatcher:
         self._thread.start()
         self._threads.append(self._thread)
 
-    @staticmethod
-    def _probe_jax() -> bool:
-        try:
-            import jax  # noqa: F401
-            return True
-        except Exception:
-            return False
-
     def _probe_donation(self) -> bool:
         """Donation is only honored on real accelerators; the CPU
         backend ignores it (with a warning per compile), so don't ask.
         The probe checks the PINNED device's platform — a mixed host
         could pin one OSD to an accelerator and another to cpu."""
-        if not self._jax:
-            return False
-        try:
-            import jax
-            dev = self.device if self.device is not None \
-                else jax.devices()[0]
-            return dev.platform not in ("cpu",)
-        except Exception:
-            return False
+        import jax
+        dev = self.device if self.device is not None \
+            else jax.devices()[0]
+        return dev.platform != "cpu"
 
     # -- public API ----------------------------------------------------
 
@@ -496,30 +464,17 @@ class TpuDispatcher:
         entry_fn = getattr(codec, "_decode_entry", None)
         if entry_fn is not None:
             def prefetch(avail=avail_rows, entry_fn=entry_fn):
-                entry = entry_fn(avail)
-                if self._jax and isinstance(entry, dict) \
-                        and "bitmat" in entry:
-                    # the device copy is keyed per HOME device: a
-                    # second pinned dispatcher must stage its own copy,
-                    # not consume (or clobber) the first device's
-                    from ..models.table_cache import device_entry_key
-                    devkey = device_entry_key(self.device)
-                    if devkey not in entry:
-                        import jax
-                        import jax.numpy as jnp
-                        bm = jnp.asarray(entry["bitmat"])
-                        if self.device is not None:
-                            bm = jax.device_put(bm, self.device)
-                        entry.setdefault(devkey, bm)
+                self._stage_entry(entry_fn(avail))
         return self._submit_async(
             key, lambda stacked: codec.decode_batch(avail_rows, stacked),
             chunks, trace, kind="dec", prefetch=prefetch)
 
     def _stage_entry(self, entry: dict) -> None:
         """Stage a TableCache entry's bitmatrix onto this dispatcher's
-        home device (same per-device keying as the decode prefetch)."""
-        if not (self._jax and isinstance(entry, dict)
-                and "bitmat" in entry):
+        home device. The copy is keyed per HOME device: a second pinned
+        dispatcher stages its own copy, never consuming (or clobbering)
+        the first device's."""
+        if not (isinstance(entry, dict) and "bitmat" in entry):
             return
         from ..models.table_cache import device_entry_key
         devkey = device_entry_key(self.device)
@@ -609,7 +564,7 @@ class TpuDispatcher:
         """Whether whole-object writes through this codec can ride the
         fused write transform (jax backend + matrix codec)."""
         from . import fused_transform
-        return self._jax and fused_transform.fused_supported(codec)
+        return fused_transform.fused_supported(codec)
 
     def fused_write_async(self, codec, batch: np.ndarray,
                           mode: str = "store",

@@ -120,7 +120,6 @@ def recover_sharded(codec, avail_rows, chunks, target_row, mesh=None,
     """
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     if mesh is None:
@@ -144,7 +143,7 @@ def recover_sharded(codec, avail_rows, chunks, target_row, mesh=None,
         return jax.lax.psum(jnp.sum(x.astype(jnp.uint32)),
                             (stripe, block))
 
-    total = shard_map(_partial, mesh=mesh,
+    total = jax.shard_map(_partial, mesh=mesh,
                       in_specs=P(stripe, None, block),
                       out_specs=P())(dev)
     got = int(np.asarray(total)) % (1 << 32)
@@ -173,7 +172,6 @@ def repair_sharded(codec, target, helpers, fractions, mesh=None,
     """
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     if mesh is None:
@@ -194,7 +192,7 @@ def repair_sharded(codec, target, helpers, fractions, mesh=None,
         return jax.lax.psum(jnp.sum(x.astype(jnp.uint32)),
                             (stripe, block))
 
-    total = shard_map(_partial, mesh=mesh,
+    total = jax.shard_map(_partial, mesh=mesh,
                       in_specs=P(stripe, None, block),
                       out_specs=P())(dev)
     got = int(np.asarray(total)) % (1 << 32)

@@ -11,9 +11,8 @@ and the HBM tier accounts residency under a per-device ledger
 category.  The registry itself is process-global so `mesh status`
 can render the whole placement table of a shared-process cluster.
 
-Host-only environments (no jax) degrade to a single virtual "host"
-slot: `resolve()` returns None and every consumer falls back to the
-implicit default device, exactly the pre-mesh behavior.
+A process that sees no device cannot place an OSD: `resolve()` raises
+rather than leave the daemon on an implicit default device.
 """
 
 from __future__ import annotations
@@ -24,11 +23,8 @@ __all__ = ["DevicePlacement", "PLACEMENT", "device_label", "local_device_count"]
 
 
 def _local_devices():
-    try:
-        import jax
-        return list(jax.local_devices())
-    except Exception:
-        return []
+    import jax
+    return list(jax.local_devices())
 
 
 def device_label(device) -> str:
@@ -56,7 +52,7 @@ class DevicePlacement:
       - device_index < 0 (the `osd_device_index` default): round-robin
         by osd_id over `jax.local_devices()` — deterministic, so two
         processes hosting the same OSD id agree without coordination;
-      - no jax / no devices: None (implicit default device).
+      - no local device: RuntimeError.
     """
 
     def __init__(self):
@@ -66,9 +62,8 @@ class DevicePlacement:
     def resolve(self, osd_id: int, device_index: int = -1):
         devices = _local_devices()
         if not devices:
-            with self._lock:
-                self._table[int(osd_id)] = (-1, None)
-            return None
+            raise RuntimeError("osd.%d: no local jax device to place on"
+                               % osd_id)
         if device_index is None or device_index < 0:
             index = int(osd_id) % len(devices)
         else:

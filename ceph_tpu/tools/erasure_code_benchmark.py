@@ -28,6 +28,7 @@ import time
 import numpy as np
 
 from .. import registry
+from ..common.compile_cache import configure_compile_cache
 from ..errors import ErasureCodeError
 
 
@@ -224,6 +225,7 @@ class ErasureCodeBench:
 
 def main(argv: list[str] | None = None) -> int:
     args = build_parser().parse_args(argv)
+    configure_compile_cache()
     try:
         return ErasureCodeBench(args).run()
     except ErasureCodeError as e:

@@ -1,5 +1,5 @@
 """CRUSH differential tests: Python/JAX reimplementation vs the reference
-C core compiled at test time (bit-exactness is the contract — BASELINE.md
+C core compiled at test time (bit-exactness is the contract — BASELINE.json
 correctness gate: batched mapping exhaustively equal to crush_do_rule).
 """
 

@@ -80,7 +80,6 @@ def test_psum_reduction_over_mesh(codec, payload):
     shape) rides psum over the mesh and matches numpy."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     m = pmesh.make_mesh(8)
@@ -91,7 +90,7 @@ def test_psum_reduction_over_mesh(codec, payload):
         def local(block):
             s = jnp.sum(block.astype(jnp.int64), axis=(0, 2))
             return jax.lax.psum(jax.lax.psum(s, "block"), "stripe")
-        return shard_map(
+        return jax.shard_map(
             local, mesh=m,
             in_specs=P("stripe", None, "block"),
             out_specs=P())(x)
