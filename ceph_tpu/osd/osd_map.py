@@ -42,9 +42,10 @@ __all__ = ["PGID", "PGPool", "OSDMap", "Incremental", "OSDMapMapping",
            "POOL_TYPE_REPLICATED", "POOL_TYPE_ERASURE", "CRUSH_ITEM_NONE"]
 
 
-def crush_fingerprint(cmap: CrushMap) -> bytes:
-    """Digest of everything a CRUSH mapping reads: buckets, rules,
-    tunables and choose_args. Equal digests map every input alike."""
+def placement_digest(cmap: CrushMap, weights: np.ndarray) -> bytes:
+    """16-byte digest of everything a raw CRUSH mapping reads besides
+    its input: buckets, rules, tunables, choose_args and the reweight
+    vector. Equal digests map every input alike."""
     h = hashlib.blake2b(digest_size=16)
     for bid in sorted(cmap.buckets):
         b = cmap.buckets[bid]
@@ -60,16 +61,18 @@ def crush_fingerprint(cmap: CrushMap) -> bytes:
                 v = (arg or {}).get(key)
                 h.update(b"-" if v is None
                          else np.asarray(v, dtype=np.int64).tobytes())
+    h.update(np.ascontiguousarray(weights, dtype=np.int64).tobytes())
     return h.digest()
 
 
 class _RawMappingCache:
-    """Process-wide memo of raw CRUSH mappings. A mapping is a pure
-    function of (crush content, rule, input, size, reweights, choose_args
-    set), so it holds across map epochs that change none of them (an OSD
-    going down or up, a pool snapshot) and across every daemon sharing
-    the process — each of which would otherwise rerun the scalar
-    mapper for every PG on every epoch."""
+    """Process-wide LRU memo of raw CRUSH rows, keyed by (placement
+    digest, pool, rule, input, size). A raw row reads nothing else, so
+    it holds across the epochs that leave CRUSH and the weights alone
+    (an OSD going down or up, a snapshot, pg_temp) and across the
+    daemons of one process: each re-maps every PG on every epoch, and
+    the scalar mapper costs tens of ms a row on a CPU core. An entry
+    is a few hundred bytes."""
 
     def __init__(self, max_entries: int = 1 << 16):
         self._lock = threading.Lock()
@@ -377,8 +380,8 @@ class OSDMap:
             # choose_args index; the balancer writes per-pool or
             # default weight-sets)
             weights = self._weight_vector()
-            key = (crush_fingerprint(self.crush), ruleno, pps, pool.size,
-                   weights.tobytes(), pgid.pool)
+            key = (placement_digest(self.crush, weights), pgid.pool,
+                   ruleno, pps, pool.size)
             row = _RAW_MAPPINGS.get(key)
             if row is None:
                 row = tuple(crush_do_rule(self.crush, ruleno, pps,

@@ -14,8 +14,10 @@ One chip (no arguments):
   read      every object read back and compared byte for byte; the
             shards of 8 objects pulled from the OSD stores and their
             parity checked against ops/gf_ref.py
-  degraded  3 OSDs holding data shards of those objects stopped, the
-            objects read degraded (decoded) and compared, OSDs restarted
+  degraded  3 OSDs holding a data shard of each of those objects
+            stopped, each object read degraded (rebuilt by a device
+            decode, or by the codec's host XOR shortcut when it lost one
+            data shard only) and compared, OSDs restarted
   crush     a 1000-OSD straw2 map (40 hosts x 25, chooseleaf indep 11
             over host) remapped for 32768 PGs by crush/batched.py and
             512 seeds compared with crush/mapper_ref.py
@@ -62,8 +64,11 @@ POOL = "smoke-ec"
 MiB = 1 << 20
 OSDS, PG_NUM = 12, 64
 OBJECT_SIZE, INFLIGHT = 4 * MiB, 16      # rados bench defaults
+OBJECTS = {1: 256, 4: 64}                # objects written, by chip count
+SAMPLE = 8               # objects whose shards are checked, read degraded
+CRUSH_HOSTS, CRUSH_PER_HOST, CRUSH_PGS = 40, 25, 32768
 CRUSH_REF_SEEDS = 512
-PLATFORM = "tpu"             # the only device platform the smoke accepts
+ECBENCH_SIZE = MiB
 
 # a stopped OSD must not be marked out and backfilled around during the
 # degraded-read drill (upstream's default down-out interval is 600 s),
@@ -93,13 +98,13 @@ def require_tpu(count: int) -> list:
     import jax
     devices = jax.devices()
     platform = devices[0].platform
-    if platform != PLATFORM:
+    if platform != "tpu":
         raise SmokeFailure("no TPU found: JAX's default backend is %r"
                            % platform)
     check(len(devices) >= count,
           "%d TPU chips wanted, %d found" % (count, len(devices)))
     devices = devices[:count]
-    check(all(d.platform == PLATFORM for d in devices),
+    check(all(d.platform == "tpu" for d in devices),
           "mixed device platforms: %s" % devices)
     return devices
 
@@ -257,6 +262,43 @@ def profiler_count(kernel: str) -> int:
     return k.get("compiles", 0) + k.get("cache_hits", 0)
 
 
+def decoded_bytes(cluster) -> int:
+    return sum(dispatcher_counters(o)["l_tpu_dec_bytes"]
+               for o in cluster.osds.values())
+
+
+def xor_shortcuts(cluster) -> int:
+    """Single-erasure XOR rebuilds done by the codecs of every PG."""
+    codecs = {id(pg.backend.codec): pg.backend.codec
+              for osd in cluster.osds.values()
+              for pg in list(osd.pgs.values())
+              if hasattr(pg.backend, "codec")}
+    return sum(c.xor_fast_hits for c in codecs.values())
+
+
+def pick_victims(client, pool_id, sample, count: int = 3) -> list:
+    """`count` OSDs that together hold a data shard of every sampled
+    object, so each of their reads has to rebuild. Greedy by objects
+    covered; among equals, an OSD that is no sampled object's primary
+    (its PGs then need no new primary before the read)."""
+    holders, primaries = {}, set()
+    for i in sample:
+        _, acting, primary = acting_of(client, pool_id, oid_of(i))
+        holders[i] = set(acting[:K])
+        primaries.add(primary)
+    victims, uncovered = [], set(sample)
+    candidates = sorted(set().union(*holders.values()))
+    for _ in range(count):
+        best = max(candidates, key=lambda o: (
+            sum(o in holders[i] for i in uncovered), o not in primaries))
+        victims.append(best)
+        candidates.remove(best)
+        uncovered -= {i for i in uncovered if best in holders[i]}
+    check(not uncovered, "no %d OSDs hold a data shard of every sampled "
+          "object (objects %s left)" % (count, sorted(uncovered)))
+    return victims
+
+
 def stop_osds(cluster, client, victims) -> dict:
     stores = {o: cluster.stop_osd(o) for o in victims}
     for o in victims:
@@ -289,7 +331,7 @@ def storage_phases(args, devices, every_osd: bool = True) -> dict:
         k: v for k, v in PROFILE.items()
         if k != "crush-failure-domain"}).coding
     size = OBJECT_SIZE
-    n_objects = args.objects
+    n_objects = OBJECTS[len(devices)]
     t0 = time.perf_counter()
     cluster, client = boot_cluster(OSDS)
     try:
@@ -301,7 +343,7 @@ def storage_phases(args, devices, every_osd: bool = True) -> dict:
                time.perf_counter() - t0))
         for osd in cluster.osds.values():
             check(osd.home_device is not None
-                  and osd.home_device.platform == PLATFORM,
+                  and osd.home_device.platform == "tpu",
                   "osd.%d home device %r" % (osd.whoami, osd.home_device))
 
         ids = list(range(n_objects))
@@ -329,29 +371,35 @@ def storage_phases(args, devices, every_osd: bool = True) -> dict:
             "(%.1f MB/s smoke timing)"
             % (n_objects, cold, warm, (n_objects - 1) * size / warm / 1e6))
 
-        sample = ids[::max(1, n_objects // args.sample)][:args.sample]
+        sample = ids[::n_objects // SAMPLE]
         for i in sample:
             check_shards(cluster, client, pool_id, args.seed, i, size,
                          coding)
         say("shards: %d objects' data shards striped right, parity equal "
             "to gf_ref" % len(sample))
 
-        # three OSDs holding data shards of the first sampled object,
-        # none of them its primary, so its read has to decode
-        _, acting, primary = acting_of(client, pool_id, oid_of(sample[0]))
-        victims = [o for o in acting[:K] if o != primary][:3]
-        dec_before = sum(dispatcher_counters(o)["l_tpu_dec_bytes"]
-                         for o in cluster.osds.values())
+        victims = pick_victims(client, pool_id, sample)
         stores = stop_osds(cluster, client, victims)
-        cold = read_objects(ioctx, args.seed, sample[:1], size, 1)
-        warm = read_objects(ioctx, args.seed, sample[1:], size, INFLIGHT)
-        dec_after = sum(dispatcher_counters(o)["l_tpu_dec_bytes"]
-                        for o in cluster.osds.values())
-        check(dec_after > dec_before,
-              "degraded reads decoded nothing on the device")
-        say("degraded: osds %s stopped, %d objects read degraded "
-            "byte-exact; cold %.3f s, warm %.3f s; decoded %d B on device"
-            % (victims, len(sample), cold, warm, dec_after - dec_before))
+        # one object at a time, so each read's rebuild is seen: a
+        # device decode, or (one data shard lost, the XOR parity alive)
+        # the codec's single-erasure XOR shortcut on the host
+        times, decoded, on_device, by_xor = [], 0, 0, 0
+        for i in sample:
+            dec0, xor0 = decoded_bytes(cluster), xor_shortcuts(cluster)
+            times.append(read_objects(ioctx, args.seed, [i], size, 1))
+            grew = decoded_bytes(cluster) - dec0
+            xor = xor_shortcuts(cluster) - xor0
+            check(grew > 0 or xor > 0,
+                  "degraded read of object %d rebuilt nothing" % i)
+            decoded += grew
+            on_device += grew > 0
+            by_xor += grew == 0
+        check(on_device > 0, "no degraded read decoded on the device")
+        say("degraded: osds %s stopped; each of %d objects lost a data "
+            "shard and read back byte-exact, %d decoded on the device "
+            "(%d B), %d by the host XOR shortcut; cold %.3f s, warm %.3f s"
+            % (victims, len(sample), on_device, decoded, by_xor, times[0],
+               sum(times[1:])))
         t1 = time.perf_counter()
         restart_osds(cluster, stores)
         say("degraded: osds %s restarted, cluster clean in %.3f s; peak "
@@ -398,9 +446,8 @@ def crush_phase(args) -> None:
     from ceph_tpu.crush.batched import batched_do_rule
     from ceph_tpu.crush.mapper_ref import crush_do_rule
     from ceph_tpu.crush.map import CRUSH_ITEM_NONE
-    cmap, reweight = crush_map(args.seed, args.crush_hosts,
-                               args.crush_per_host)
-    xs = np.arange(args.crush_pgs, dtype=np.int64)
+    cmap, reweight = crush_map(args.seed, CRUSH_HOSTS, CRUSH_PER_HOST)
+    xs = np.arange(CRUSH_PGS, dtype=np.int64)
     t0 = time.perf_counter()
     out = np.asarray(batched_do_rule(cmap, 0, xs, K + M, reweight))
     cold = time.perf_counter() - t0
@@ -421,7 +468,7 @@ def crush_phase(args) -> None:
     say("crush: %d OSDs (%d hosts x %d), %d PGs, chooseleaf indep %d; "
         "cold %.3f s, warm %.3f s (%.0f PGs/s smoke timing); %d seeds "
         "equal to mapper_ref"
-        % (len(reweight), args.crush_hosts, args.crush_per_host, len(xs),
+        % (len(reweight), CRUSH_HOSTS, CRUSH_PER_HOST, len(xs),
            K + M, cold, warm, len(xs) / warm, len(seeds)))
 
 
@@ -438,7 +485,7 @@ def run_tool(argv) -> tuple:
 def ecbench_phase(args) -> None:
     from ceph_tpu import registry
     from ceph_tpu.ops import gf_ref
-    size = args.ecbench_size
+    size = ECBENCH_SIZE
     base = ["-p", "jax_tpu", "-P", "technique=reed_sol_van",
             "-P", "k=%d" % K, "-P", "m=%d" % M, "-s", str(size)]
     rc, out = run_tool(base + ["--batch", "16", "-i", "20"])
@@ -480,7 +527,7 @@ def multichip_phases(args, devices) -> None:
     placed = PLACEMENT.assignments()
     labels = {row["device"] for row in placed["osds"].values()}
     check(len(labels) == len(devices) and all(
-        lbl.startswith(PLATFORM + ":") for lbl in labels),
+        lbl.startswith("tpu:") for lbl in labels),
         "OSDs placed on %s" % sorted(labels))
     per_dev = {}
     for dev, counters in evidence.values():
@@ -490,9 +537,8 @@ def multichip_phases(args, devices) -> None:
     say("placement: %d OSDs over %s; dispatches per chip %s"
         % (len(evidence), sorted(labels), per_dev))
 
-    cmap, reweight = crush_map(args.seed, args.crush_hosts,
-                               args.crush_per_host)
-    xs = np.arange(args.crush_pgs, dtype=np.int64)
+    cmap, reweight = crush_map(args.seed, CRUSH_HOSTS, CRUSH_PER_HOST)
+    xs = np.arange(CRUSH_PGS, dtype=np.int64)
     single = np.asarray(batched_do_rule(cmap, 0, xs, K + M, reweight))
     mesh = make_batch_mesh(len(devices))
     t0 = time.perf_counter()
@@ -527,22 +573,11 @@ def build_parser() -> argparse.ArgumentParser:
         prog="chip_smoke", description=__doc__.split("\n\n")[0])
     p.add_argument("--chips", type=int, default=1, choices=(1, 4))
     p.add_argument("--seed", type=int, default=0)
-    p.add_argument("--objects", type=int, default=None,
-                   help="objects written (default 256; 64 with --chips 4)")
-    p.add_argument("--sample", type=int, default=8,
-                   help="objects whose shards are checked and read "
-                        "degraded")
-    p.add_argument("--crush-hosts", type=int, default=40)
-    p.add_argument("--crush-per-host", type=int, default=25)
-    p.add_argument("--crush-pgs", type=int, default=32768)
-    p.add_argument("--ecbench-size", type=int, default=MiB)
     return p
 
 
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
-    if args.objects is None:
-        args.objects = 256 if args.chips == 1 else 64
     from ceph_tpu.common.compile_cache import configure_compile_cache
     cache_dir = configure_compile_cache()
     phase = "device"

@@ -90,7 +90,12 @@ def test_fused_store_program_fits_beside_residency(compile_for, codec,
                                                    shape):
     """The fused write program for one 4 MiB object, in both batch
     layouts the OSD can stage, stays far below the chip's 16 GB (the
-    OSDs' resident chunks share it)."""
+    OSDs' resident chunks share it).
+
+    memory_analysis() for a described v5e reads 0.27 GB of temporaries
+    in both layouts with the lane-major CRC tree, against 3.06 GB
+    ([1,8,524288]) and 3.29 GB ([128,8,4096]) when every tree level
+    ended in an axis of 2; the 1 GB bound holds that repair."""
     from ceph_tpu.osd import fused_transform as ft
     z = ft._poly_consts(ft._POLY_ZLIB)
     c = ft._poly_consts(ft._POLY_C)
@@ -102,7 +107,9 @@ def test_fused_store_program_fits_beside_residency(compile_for, codec,
         (c.table.shape, c.table.dtype), ((), np.uint32), ((), np.uint32),
         w=W, mode="store", required_milli=875, entropy_max_milli=7000,
         cap2=S * k * chunk, stripe_width=k * chunk)
-    assert compiled.memory_analysis().temp_size_in_bytes < 8 * GB
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 8 * GB
+    assert temp < GB
 
 
 def test_crush_indep_kernel_compiles(compile_for):
