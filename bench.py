@@ -91,17 +91,9 @@ consistency gate (restated for the overlapped path)
              summed durations by a margin — overlap that never
              happened is a regression, not a measurement detail.
 
---trace adds a `trace_breakdown` row: per-phase {h2d, compute, d2h,
-dispatch_queue} device-time attribution measured through the
-production TpuDispatcher + common.tracer.device_segments
-instrumentation (the same code path the OSD's op spans and l_tpu_*
-counters ride), smoke-gated so segment sums can never exceed the wall
-time they decompose.  The row also carries `stall_attribution` — the
-dispatch-profile verdict plus {collector_idle, h2d_blocked,
-compute_busy, d2h_blocked} fractions from the stage profiler.  Every
-run additionally prices the DeviceProfiler itself (profiler_overhead
-row): profiler-on streaming must land within 3% of profiler-off or
-the run FAILS — the observability layer may not tax the data path.
+Every run prices the DeviceProfiler itself (profiler_overhead row):
+profiler-on streaming must land within 3% of profiler-off or the run
+FAILS — the observability layer may not tax the data path.
 
 Trustworthiness protocol (VERDICT #2): every headline row is timed
 over REPEATS (>= 3) INTERLEAVED repeats — rep 1 of all rows before
@@ -504,9 +496,8 @@ def _bench_cluster() -> dict:
     out: dict = {}
     # tracing AND telemetry reporting off for this row: it prices the
     # PIPELINE and must stay methodology-constant with earlier rounds
-    # (the --trace breakdown row measures the instrumented path
-    # separately; mgr_stats_period=0 pins the MMgrReport stream off
-    # the same way osd_tracing=False pins the span path)
+    # (mgr_stats_period=0 pins the MMgrReport stream off the same way
+    # osd_tracing=False pins the span path)
     c = MiniCluster(num_mons=1, num_osds=4,
                     conf_overrides={"osd_tracing": False,
                                     "osd_profiler": False,
@@ -592,66 +583,6 @@ def _bench_cluster() -> dict:
     finally:
         c.stop()
     return out
-
-
-def _trace_breakdown(codec, data_host) -> dict:
-    """--trace: the per-phase device-time attribution row (ISSUE:
-    observability).  Runs encodes through the PRODUCTION TpuDispatcher
-    with tracing armed, so the {h2d, compute, d2h, dispatch_queue}
-    numbers come from the same common.tracer.device_segments
-    instrumentation the OSD's spans and l_tpu_* counters use — not a
-    bench-only approximation.  Smoke-gates segment sums against wall
-    time (a segment sum exceeding the wall it decomposes is a timing
-    artifact and fails the run)."""
-    from ceph_tpu.common.tracer import SpanCollector
-    from ceph_tpu.osd.tpu_dispatch import TpuDispatcher
-
-    tracer = SpanCollector()
-    tracer.enabled = True
-    disp = TpuDispatcher(max_batch=4, max_delay=0.0005, tracer=tracer)
-    try:
-        reps = 5
-        t0 = time.perf_counter()
-        for _ in range(reps):
-            root = tracer.start_trace("bench_encode")
-            disp.encode(codec, data_host, trace=root)
-            root.finish()
-        wall = (time.perf_counter() - t0) / reps
-        perf = disp.perf
-        seg = {
-            "h2d_s": perf.avg("l_tpu_h2d"),
-            "compute_s": perf.avg("l_tpu_compute"),
-            "d2h_s": perf.avg("l_tpu_d2h"),
-            "dispatch_queue_s": perf.avg("l_tpu_dispatch_queue"),
-        }
-        # smoke assertion: the segments decompose one dispatch's wall
-        # time — their sum can never exceed it (small slack for clock
-        # granularity on sub-ms segments)
-        total = sum(seg.values())
-        if total > wall * 1.05 + 1e-4:
-            raise SystemExit(
-                "--trace gate: segment sum %.6fs exceeds wall %.6fs — "
-                "device-time attribution is broken" % (total, wall))
-        seg["wall_s"] = wall
-        seg["spans"] = len(tracer.dump())
-        out = {k: (round(v, 6) if isinstance(v, float) else v)
-               for k, v in seg.items()}
-        # stall attribution from the dispatcher's profile window: the
-        # four numbers an operator reads first when asking "which
-        # stage is the wall" (busy time of the device stages, idle/
-        # blocked time of their neighbors), plus the verdict itself
-        prof = disp.dispatch_profile()
-        stages = prof["stages"]
-        out["stall_attribution"] = {
-            "verdict": prof["verdict"],
-            "collector_idle": stages["collector"]["idle_frac"],
-            "h2d_blocked": stages["h2d"]["blocked_frac"],
-            "compute_busy": stages["compute"]["busy_frac"],
-            "d2h_blocked": stages["d2h"]["blocked_frac"],
-        }
-        return out
-    finally:
-        disp.shutdown()
 
 
 def _profiler_overhead_gate(codec, data_host) -> dict:
@@ -1063,9 +994,7 @@ def _run_worker(flag: str, timeout: float) -> dict:
     that fails, times out or prints no result fails the run."""
     here = os.path.abspath(__file__)
     argv = [sys.executable, here, flag] + (
-        ["--cpu"] if "--cpu" in sys.argv else []) + (
-        ["--trace"] if "--trace" in sys.argv and flag == "--worker"
-        else [])
+        ["--cpu"] if "--cpu" in sys.argv else [])
     try:
         proc = subprocess.run(argv, timeout=timeout, capture_output=True,
                               text=True)
@@ -4103,14 +4032,6 @@ def run_bench() -> None:
     # profiler-off, SystemExit otherwise)
     print("BENCH-STAGE profiler-overhead", file=sys.stderr, flush=True)
     doc["profiler_overhead"] = _profiler_overhead_gate(tpu, data_host)
-
-    # --trace: per-phase {h2d, compute, d2h, dispatch_queue} breakdown
-    # through the production dispatcher instrumentation (runs after the
-    # seal — its reads are d2h and the timed sections are in hand)
-    if "--trace" in sys.argv:
-        print("BENCH-STAGE trace-breakdown", file=sys.stderr,
-              flush=True)
-        doc["trace_breakdown"] = _trace_breakdown(tpu, data_host)
 
     doc.update(dec_e)
     doc.update(native)

@@ -26,11 +26,9 @@ Pieces:
                  fragments until the verdict and dropped traces cost
                  zero wire bytes.
   trace_ctx      (trace_id, parent_span_id) for a message envelope.
-  device_segments  the one device-call shape everyone shares: run a
-                 codec call split into h2d / compute / d2h segments
-                 (TpuDispatcher device spans and bench.py --trace both
-                 ride it, so the bench breakdown and the production
-                 spans measure the same thing).
+  device_segments  run a codec call split into h2d / compute / d2h
+                 segments (the TpuDispatcher's synchronous path times
+                 its device spans with it).
   render_tree    the `ceph trace tree` renderer: stitched cross-daemon
                  span tree with per-span self-times.
 """
@@ -66,41 +64,64 @@ class Span:
 
     __slots__ = ("collector", "name", "endpoint", "trace_id", "span_id",
                  "parent_id", "start", "start_wall", "end", "keyvals",
-                 "events")
+                 "events", "open_stage")
 
     def __init__(self, collector, name, endpoint="", trace_id=None,
-                 parent_id=None):
+                 parent_id=None, start=None, parent=None):
         self.collector = collector
         self.name = name
         self.endpoint = endpoint
         self.span_id = _next_id()
+        if parent is not None:
+            trace_id, parent_id = parent.trace_id, parent.span_id
         self.trace_id = trace_id if trace_id else self.span_id
         self.parent_id = parent_id
-        self.start = time.monotonic()
-        self.start_wall = time.time()
+        now = time.monotonic()
+        # `start` backdates a span to when it began, before it was
+        # minted (e.g. at the messenger's receipt of its message)
+        self.start = now if start is None else start
+        if parent is not None:
+            # one wall axis for a daemon's part of a trace: offsets
+            # from the parent are the monotonic clock's, so spans that
+            # abut there abut on the wall axis too
+            self.start_wall = parent.start_wall + (self.start
+                                                   - parent.start)
+        else:
+            self.start_wall = time.time() - (now - self.start)
         self.end: float | None = None
         self.keyvals: dict = {}
         self.events: list[tuple[float, str]] = []
+        self.open_stage: Span | None = None
 
     def valid(self) -> bool:
         return True
 
     def child(self, name: str) -> "Span":
-        return Span(self.collector, name, self.endpoint,
-                    trace_id=self.trace_id, parent_id=self.span_id)
+        return Span(self.collector, name, self.endpoint, parent=self)
 
     def child_interval(self, name: str, start: float, end: float,
                        **keyvals) -> "Span":
         """Record an already-measured interval as a finished child
         (monotonic stamps) — how the dispatcher back-fills queue-delay
         and device-segment spans it could only time, not wrap."""
-        s = self.child(name)
-        s.start_wall = s.start_wall - (s.start - start)
-        s.start = start
+        s = Span(self.collector, name, self.endpoint, start=start,
+                 parent=self)
         s.keyvals.update(keyvals)
         s.end = end
         s.collector._record(s)
         return s
+
+    def stage(self, name: str) -> "Span":
+        """Open a child that ends where the op's next step takes over:
+        that step calls `end_stage()`, and whoever opened the stage
+        still finishes it (a no-op once ended) when no step did."""
+        self.open_stage = self.child(name)
+        return self.open_stage
+
+    def end_stage(self) -> None:
+        s, self.open_stage = self.open_stage, None
+        if s is not None:
+            s.finish()
 
     def keyval(self, key: str, value) -> None:
         self.keyvals[key] = value
@@ -158,6 +179,12 @@ class _NullSpan:
 
     def child_interval(self, name, start, end, **kv) -> "_NullSpan":
         return self
+
+    def stage(self, name: str) -> "_NullSpan":
+        return self
+
+    def end_stage(self) -> None:
+        pass
 
     def keyval(self, key: str, value) -> None:
         pass
@@ -257,23 +284,28 @@ class SpanCollector:
                 conf.add_observer(_Obs())
         self.capacity = capacity
         self._spans: deque[Span] = deque(maxlen=capacity)
+        #: spans the full ring pushed out, since start or `trace reset`
+        self.dropped = 0
         #: optional TailSampler: every recorded span is offered to it
         #: so replicas can buffer fragments pending the root's verdict
         self.tail = None
 
     # -- span minting --------------------------------------------------
 
-    def start_trace(self, name: str, endpoint: str | None = None):
-        """Root span (sampling applies here), or NULL_SPAN."""
+    def start_trace(self, name: str, endpoint: str | None = None,
+                    start=None):
+        """Root span (sampling applies here), or NULL_SPAN. `start`
+        backdates it (a monotonic stamp), as `continue_trace`'s does."""
         if not self.enabled:
             return NULL_SPAN
         if self.sample > 1 and next(self._sample_ctr) % self.sample:
             return NULL_SPAN
         return Span(self, name,
-                    self.endpoint if endpoint is None else endpoint)
+                    self.endpoint if endpoint is None else endpoint,
+                    start=start)
 
     def continue_trace(self, name: str, trace_id: int, parent_id: int,
-                       endpoint: str | None = None):
+                       endpoint: str | None = None, start=None):
         """Stitch onto a trace context from a message envelope; the
         sampling decision was the root's — a nonzero trace_id means the
         originator chose to trace this op."""
@@ -281,12 +313,15 @@ class SpanCollector:
             return NULL_SPAN
         return Span(self, name,
                     self.endpoint if endpoint is None else endpoint,
-                    trace_id=trace_id, parent_id=parent_id or None)
+                    trace_id=trace_id, parent_id=parent_id or None,
+                    start=start)
 
     # -- storage -------------------------------------------------------
 
     def _record(self, span: Span) -> None:
         with self._lock:
+            if len(self._spans) == self.capacity:
+                self.dropped += 1
             self._spans.append(span)
         tail = self.tail
         if tail is not None:
@@ -301,6 +336,7 @@ class SpanCollector:
     def clear(self) -> None:
         with self._lock:
             self._spans.clear()
+            self.dropped = 0
 
     # -- admin socket surface ------------------------------------------
 
@@ -310,7 +346,8 @@ class SpanCollector:
             tid = int(tid, 0) if isinstance(tid, str) else tid
             spans = self.dump(tid)
             return {"enabled": self.enabled, "sample": self.sample,
-                    "num_spans": len(spans), "spans": spans}
+                    "num_spans": len(spans), "dropped": self.dropped,
+                    "spans": spans}
 
         asok.register("dump_tracing", _dump,
                       "dump collected op spans (optional trace_id)")
@@ -476,10 +513,8 @@ class TailSampler:
 def device_segments(fn, batch):
     """Run fn(batch) as an explicit h2d -> compute -> d2h sequence and
     time each leg.  Returns (host ndarray result, {"h2d", "compute",
-    "d2h"} seconds).  The TpuDispatcher's device spans and bench.py
-    --trace both use this, so the artifact breakdown and production
-    spans measure the identical call shape.  Falls back to one
-    unsegmented call (all time under "compute") when jax is absent."""
+    "d2h"} seconds).  Falls back to one unsegmented call (all time
+    under "compute") when jax is absent."""
     t0 = time.perf_counter()
     try:
         import jax

@@ -52,6 +52,12 @@ def _approx_span_bytes(span: dict) -> int:
             + 48 * len(span.get("events") or ()))
 
 
+#: siblings that overlap by less than this abut: a wall stamp near the
+#: epoch's magnitude rounds to about 0.2 us, so the end of one span and
+#: the start of the next it hands over to rarely compare equal
+_ABUT = 1e-6
+
+
 def critical_path(spans: list[dict]) -> list[tuple[str, float]]:
     """The trace's critical path as [(stage, seconds), ...].
 
@@ -91,7 +97,7 @@ def critical_path(spans: list[dict]) -> list[tuple[str, float]]:
         p = []
         for i in range(n):
             j = i - 1
-            while j >= 0 and ends[j] > starts[i] + 1e-12:
+            while j >= 0 and ends[j] > starts[i] + _ABUT:
                 j -= 1
             p.append(j)
         best = [0.0] * (n + 1)

@@ -155,6 +155,7 @@ class AsyncConnection(Connection):
         super().__init__(msgr, peer_addr, sock=sock)
         self.center = msgr.center
         self._inbuf = bytearray()
+        self._rx_stamp = 0.0         # first bytes of the head frame read
         # protocol/handshake bytes (regenerated per connection) flush
         # ahead of data; exactly ONE message frame is in flight at a
         # time and its message stays at the head of out_q until fully
@@ -487,6 +488,10 @@ class AsyncConnection(Connection):
         sock = self.sock
         if sock is None:
             return
+        # when the first bytes of the frame at the buffer's head were
+        # read: this event's read, unless a partial frame waited
+        now = time.monotonic()
+        head_stamp = self._rx_stamp if self._inbuf else now
         try:
             while True:
                 chunk = sock.recv(65536)
@@ -521,14 +526,16 @@ class AsyncConnection(Connection):
                 was_confirmed = self.auth_confirmed
                 if not self._process_payload(payload,
                                              self._buffer_bytes,
-                                             link_seq):
+                                             link_seq, head_stamp):
                     self._teardown()
                     return
+                head_stamp = now
                 if self.auth_confirmed and not was_confirmed:
                     self._pump()     # auth landed: release held frames
         finally:
             if off and buf is self._inbuf:
                 del self._inbuf[:off]   # one compaction per event
+            self._rx_stamp = head_stamp
 
     def close(self) -> None:
         with self.lock:

@@ -464,6 +464,7 @@ class Connection:
                 hdr = _read_exact(sock, _HDR.size)
                 if hdr is None:
                     break
+                recv_stamp = time.monotonic()
                 magic, length, link_seq, sig = _HDR.unpack(hdr)
                 if magic != _MAGIC:
                     break
@@ -483,7 +484,7 @@ class Connection:
                     pass
                 break
             if not self._process_payload(payload, self._queue_ctrl,
-                                         link_seq):
+                                         link_seq, recv_stamp):
                 break
         if sock is self.sock:
             self.sock = None
@@ -515,13 +516,15 @@ class Connection:
                     self.cond.notify_all()
 
     def _process_payload(self, payload: bytes, send_bytes,
-                         link_seq: int = 0) -> bool:
+                         link_seq: int = 0,
+                         recv_stamp: float = 0.0) -> bool:
         """One inbound frame through the connection protocol (banner
         handshake, restricted pre-auth decode, dispatch). Transport
         agnostic: the threaded reader passes sock.sendall, the async
         engine passes its buffered writer. link_seq is the frame
-        header's per-connection sequence (0 = control frame). Returns
-        False to tear the connection down."""
+        header's per-connection sequence (0 = control frame);
+        recv_stamp the monotonic time the frame's first bytes were
+        read. Returns False to tear the connection down."""
         # pre-auth frames may only materialize closed-set builtins
         # (no registered-struct construction), so an unauthenticated
         # peer cannot reach any type's constructor
@@ -762,6 +765,11 @@ class Connection:
                 return True
             self._in_seq = max(self._in_seq, seq)
         release = self._throttle_admit(msg, len(payload))
+        # receive-side stamps (never encoded): the daemon's op span
+        # starts at the receipt, and its `ms_recv` child covers the
+        # read, decode and throttle wait up to dispatch
+        msg.recv_stamp = recv_stamp
+        msg.dispatch_stamp = time.monotonic()
         self.msgr._dispatch(msg)
         if release is not None \
                 and not getattr(msg, "_throttle_adopted", False):

@@ -30,6 +30,7 @@ from __future__ import annotations
 import itertools
 import json
 import threading
+import time
 
 import numpy as np
 
@@ -57,6 +58,9 @@ class _InflightWrite:
         self.at_version = at_version
         self.on_commit = on_commit
         self.trace = trace            # the client op's span (or null)
+        # the backend took the op here (the start of its ec_wait span)
+        self.t_submit = time.monotonic() if trace.valid() else 0.0
+        self.commit_span = NULL_SPAN  # parent of the sub-write spans
         self.sub_spans: dict = {}     # shard -> per-shard sub-write span
         self.plan = None
         self.pin = None
@@ -76,6 +80,7 @@ class _InflightRead:
         self.length = length
         self.on_done = on_done
         self.trace = trace
+        self.gather_span = NULL_SPAN  # parent of the sub-read spans
         self.sub_spans: dict = {}     # shard -> per-shard sub-read span
         self.raw_shards_cb = None     # recovery: wants raw shard streams
         self.shard_data: dict = {}    # shard -> bytes
@@ -152,10 +157,11 @@ class ECBackend:
     def submit_transaction(self, pg_txn, at_version: int,
                            on_commit, reqid: tuple = ("", 0),
                            trace=NULL_SPAN) -> int:
+        trace = trace if trace is not None else NULL_SPAN
+        trace.end_stage()             # the PG's planning ends here
         tid = next(self._tids)
         op = _InflightWrite(tid, pg_txn, at_version, on_commit,
-                            trace=trace if trace is not None
-                            else NULL_SPAN)
+                            trace=trace)
         op.reqid = reqid
         with self.lock:
             self.waiting_state.append(op)
@@ -230,6 +236,10 @@ class ECBackend:
             # tpu_device segments nest beneath it (ECBackend.cc:1857's
             # try_reads_to_commit is where the codec runs)
             enc_span = op.trace.child("ec_encode")
+            if enc_span.valid():
+                # PG order and RMW reads held the op this long
+                op.trace.child_interval("ec_wait", op.t_submit,
+                                        enc_span.start)
             txns, written = ec_transaction.generate_transactions(
                 op.plan, self.codec, self.sinfo, partial,
                 list(range(self.n)), self.pg.cid_of_shard,
@@ -258,11 +268,13 @@ class ECBackend:
                 op.plan.t.op_map, op.at_version,
                 getattr(op, "reqid", ("", 0)))
         op.sub_msgs = {}
+        # first sub-write sent -> last commit handled
+        op.commit_span = op.trace.child("commit_wait")
         for shard, osd in shards.items():
             if osd == CRUSH_ITEM_NONE:
                 continue
             # one child span per shard sub-write (ECBackend.cc:1978-83)
-            sub_span = op.trace.child("sub_write(shard=%d)" % shard)
+            sub_span = op.commit_span.child("sub_write(shard=%d)" % shard)
             sub_span.keyval("osd", osd)
             op.sub_spans[shard] = sub_span
             t_id, p_id = trace_ctx(sub_span)
@@ -324,6 +336,7 @@ class ECBackend:
             op.sub_spans = {}
         for span in spans:   # shards dropped mid-interval finish here
             span.finish()
+        op.commit_span.finish()
         if on_commit:
             on_commit()
         self.check_ops()
@@ -355,9 +368,7 @@ class ECBackend:
             return
         # replica-side span, stitched under the primary's per-shard
         # child via the envelope context (covers store apply + commit)
-        span = self.pg.daemon.tracer.continue_trace(
-            "ec_sub_write", getattr(msg, "trace_id", 0),
-            getattr(msg, "parent_span", 0))
+        span = self._sub_op_span("ec_sub_write", msg)
         span.keyval("shard", msg.shard)
         span.keyval("tid", msg.tid)
         txn = Transaction()
@@ -393,6 +404,19 @@ class ECBackend:
         txn.register_on_commit(on_commit)
         self.pg.store.queue_transaction(txn)
 
+    def _sub_op_span(self, name: str, msg):
+        """A replica's span for a sub-op, stitched under the primary's
+        per-shard child; it starts at the messenger's receipt, and its
+        ms_recv child covers up to dispatch (a local sub-op has no
+        receipt)."""
+        recv = getattr(msg, "recv_stamp", None) or None
+        span = self.pg.daemon.tracer.continue_trace(
+            name, getattr(msg, "trace_id", 0),
+            getattr(msg, "parent_span", 0), start=recv)
+        if recv:
+            span.child_interval("ms_recv", recv, msg.dispatch_stamp)
+        return span
+
     def handle_sub_write_reply(self, msg) -> None:
         target = None
         span = None
@@ -419,6 +443,8 @@ class ECBackend:
         Sub-reads the covering chunk range from the available shards
         (data shards when whole, any k when degraded), decodes if any
         data shard is missing, slices the requested range."""
+        if trace is not None:
+            trace.end_stage()         # the PG's planning ends here
         self._start_read(oid, off, length, on_done, trace=trace)
 
     def _start_read(self, oid, off, length, on_done,
@@ -477,13 +503,15 @@ class ECBackend:
         read.want_shards = set(to_read)
         read.chunk_off = chunk_off
         read.chunk_len = chunk_len
+        # first sub-read sent -> last shard in
+        read.gather_span = read.trace.child("read_gather")
         with self.lock:
             self.inflight_reads[tid] = read
         for shard in to_read:
             osd = shards_avail[shard]
             # one child span per shard sub-read, mirroring the write
             # side's per-shard children
-            sub_span = read.trace.child("sub_read(shard=%d)" % shard)
+            sub_span = read.gather_span.child("sub_read(shard=%d)" % shard)
             sub_span.keyval("osd", osd)
             read.sub_spans[shard] = sub_span
             t_id, p_id = trace_ctx(sub_span)
@@ -615,9 +643,7 @@ class ECBackend:
         check): silent bit-rot becomes an EIO in the reply, so the
         primary reconstructs around it exactly like a loud disk error
         instead of decoding garbage into the client's buffer."""
-        span = self.pg.daemon.tracer.continue_trace(
-            "ec_sub_read", getattr(msg, "trace_id", 0),
-            getattr(msg, "parent_span", 0))
+        span = self._sub_op_span("ec_sub_read", msg)
         span.keyval("shard", msg.shard)
         reply = MOSDECSubOpReadReply(
             pgid=self.pg.pgid, shard=msg.shard, from_osd=self.pg.whoami,
@@ -719,7 +745,7 @@ class ECBackend:
             return
         if msg.errors and resend is not None:
             sub, osd = resend
-            sub_span = read.trace.child("sub_read(shard=%d)" % sub)
+            sub_span = read.gather_span.child("sub_read(shard=%d)" % sub)
             sub_span.keyval("osd", osd)
             sub_span.keyval("substituted_for", msg.shard)
             with self.lock:
@@ -749,6 +775,7 @@ class ECBackend:
         for span in read.sub_spans.values():
             span.finish()        # stragglers (substituted-away shards)
         read.sub_spans = {}
+        read.gather_span.finish()
         if read.raw_shards_cb is not None:
             read.raw_shards_cb(dict(read.shard_data))
             return

@@ -21,6 +21,7 @@ device call via ec_util.encode.
 from __future__ import annotations
 
 import json
+import time
 
 import numpy as np
 
@@ -32,6 +33,25 @@ __all__ = ["WritePlan", "get_write_plan", "generate_transactions",
            "HINFO_KEY"]
 
 HINFO_KEY = "hinfo_key"  # reference ECUtil::get_hinfo_key()
+
+
+class _Legs:
+    """Back-fills the host legs of an encode as consecutive children of
+    its span: each leg runs from where the last mark left off."""
+
+    def __init__(self, span):
+        self.span = span
+        self.t = time.monotonic()
+
+    def mark(self) -> None:
+        """Start the next leg now (spans of their own cover what
+        came before, such as the codec call's)."""
+        self.t = time.monotonic()
+
+    def leg(self, name: str) -> None:
+        now = time.monotonic()
+        self.span.child_interval(name, self.t, now)
+        self.t = now
 
 
 class WritePlan:
@@ -159,7 +179,15 @@ def generate_transactions(plan: WritePlan, codec,
     "compress" additionally lets the device compress the stored
     stream; None/"off" keeps the classic encode.  Partial RMWs and
     ops carrying a truncate always take the classic path.
+
+    With a recording `trace` (the op's ec_encode span) the host work
+    is back-filled beneath it in legs: ec_assemble (the logical buffer
+    of an extent), ec_txns (the shard writes of an extent, after the
+    chunk split ec_util records under the same name; then the
+    truncates, attrs and hinfo xattr of the object) and ec_hinfo (host
+    shard crcs of an append), around the codec call's own spans.
     """
+    legs = _Legs(trace) if trace is not None and trace.valid() else None
     txns = {shard: Transaction() for shard in shards}
     written: dict = {}
     n = codec.get_chunk_count()
@@ -213,6 +241,8 @@ def generate_transactions(plan: WritePlan, codec,
                          and op.truncate is None)
             fused_res = None
             for off, length in extents:
+                if legs:
+                    legs.mark()
                 # assemble the logical bytes for this extent: readback
                 # stripes overlaid with the op's buffer updates,
                 # zero-filled elsewhere
@@ -241,6 +271,8 @@ def generate_transactions(plan: WritePlan, codec,
 
                 res = (tier, tier_key) \
                     if whole_object and tier_key is not None else None
+                if legs:
+                    legs.leg("ec_assemble")
                 if use_fused:
                     encoded, fused_res = ec_util.encode_fused(
                         sinfo, codec, buf, dispatcher=dispatcher,
@@ -253,6 +285,8 @@ def generate_transactions(plan: WritePlan, codec,
                     encoded = ec_util.encode(
                         sinfo, codec, buf, dispatcher=dispatcher,
                         trace=trace, resident=res)
+                if legs:
+                    legs.mark()
                 chunk_off = sinfo.aligned_logical_offset_to_chunk_offset(off)
                 for shard in range(n):
                     if shard in txns:
@@ -260,6 +294,8 @@ def generate_transactions(plan: WritePlan, codec,
                                           encoded[shard].tobytes())
                 wmap[off] = buf
                 appends[chunk_off] = encoded
+                if legs:
+                    legs.leg("ec_txns")
 
             # hinfo chains crcs only for pure appends (overwrites
             # invalidate the chunk hash, as in the reference's
@@ -289,6 +325,8 @@ def generate_transactions(plan: WritePlan, codec,
             elif all(off >= old_size for off in appends):
                 for chunk_off in sorted(appends):
                     hinfo.append(chunk_off, appends[chunk_off])
+                if legs:
+                    legs.leg("ec_hinfo")
             else:
                 hinfo.cumulative_shard_hashes = []
                 hinfo.total_chunk_size = max(
@@ -327,6 +365,8 @@ def generate_transactions(plan: WritePlan, codec,
             if not op.is_none() and leaves_object:
                 txn.setattr(cid, oid, HINFO_KEY,
                             json.dumps(hinfo.to_dict()).encode())
+        if legs:
+            legs.leg("ec_txns")
 
     # convert logical written maps to ExtentMaps
     from ..common.interval_set import ExtentMap

@@ -19,6 +19,7 @@ own the device.
 
 from __future__ import annotations
 
+import time
 import zlib
 
 import numpy as np
@@ -94,6 +95,9 @@ def encode(sinfo: StripeInfo, codec, data, want=None,
     STAGED device arrays (zero extra transfers); without one the tier
     adopts the host arrays itself (that h2d is then the object's one
     crossing).
+
+    A recording `trace` gets the chunk split back-filled as an
+    ``ec_txns`` child (the first host leg after the codec's result).
     """
     arr = np.frombuffer(data, dtype=np.uint8) if isinstance(
         data, (bytes, bytearray, memoryview)) else \
@@ -120,6 +124,7 @@ def encode(sinfo: StripeInfo, codec, data, want=None,
                 tier.adopt_encode(key, batch, parity, codec)
             except Exception:
                 pass   # the tier is a cache: adoption never fails a write
+    t = _split_start(trace)
     out = {}
     for i in range(n):
         idx = codec.chunk_index(i)
@@ -127,7 +132,15 @@ def encode(sinfo: StripeInfo, codec, data, want=None,
             continue
         src = batch[:, i, :] if i < k else parity[:, i - k, :]
         out[idx] = np.ascontiguousarray(src).reshape(-1)
+    if t:
+        trace.child_interval("ec_txns", t, time.monotonic())
     return out
+
+
+def _split_start(trace) -> float:
+    """When the chunk split began, for a recording trace (else 0)."""
+    return time.monotonic() if trace is not None and trace.valid() \
+        else 0.0
 
 
 def encode_fused(sinfo: StripeInfo, codec, data, want=None,
@@ -150,7 +163,8 @@ def encode_fused(sinfo: StripeInfo, codec, data, want=None,
 
     resident=(tier, key) adopts the STORED rows + shard crcs into the
     HbmChunkTier (scrub-from-digest), exactly like encode()'s resident
-    contract.
+    contract, and a recording `trace` gets the chunk split as encode()
+    gives it.
     """
     from . import fused_transform
     arr = np.frombuffer(data, dtype=np.uint8) if isinstance(
@@ -186,6 +200,7 @@ def encode_fused(sinfo: StripeInfo, codec, data, want=None,
                     digests=np.asarray(r.shard_crcs, dtype=np.uint32))
             except Exception:
                 pass   # the tier is a cache: adoption never fails
+    t = _split_start(trace)
     rows = r.stored if r.stored is not None else batch
     parity = np.asarray(r.parity)
     shard_map = {}
@@ -196,6 +211,8 @@ def encode_fused(sinfo: StripeInfo, codec, data, want=None,
         src = rows[:, i, :] if i < k else parity[:, i - k, :]
         shard_map[idx] = np.ascontiguousarray(
             np.asarray(src)).reshape(-1)
+    if t:
+        trace.child_interval("ec_txns", t, time.monotonic())
     return shard_map, r
 
 

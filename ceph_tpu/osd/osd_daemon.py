@@ -1469,12 +1469,17 @@ class OSDDaemon(Dispatcher):
         msg._pq_start = op.initiated_mono
         # stitch under the client's trace when the envelope carries a
         # context; a context-less op (old client, tracing off there)
-        # still gets an OSD-rooted trace subject to local sampling
+        # still gets an OSD-rooted trace subject to local sampling.
+        # The span starts at the messenger's receipt (OpRequest's
+        # initiated stamp), its ms_recv child covering up to dispatch
+        recv = getattr(msg, "recv_stamp", None) or None
         span = self.tracer.continue_trace(
             "osd_op", getattr(msg, "trace_id", 0),
-            getattr(msg, "parent_span", 0))
+            getattr(msg, "parent_span", 0), start=recv)
         if not span.valid():
-            span = self.tracer.start_trace("osd_op")
+            span = self.tracer.start_trace("osd_op", start=recv)
+        if recv:
+            span.child_interval("ms_recv", recv, msg.dispatch_stamp)
         span.keyval("tid", msg.tid)
         span.keyval("pg", str(msg.pgid))
         msg.trace = span   # receive-side annotation: the PG and the
@@ -1571,9 +1576,11 @@ class OSDDaemon(Dispatcher):
             span.child_interval("op_queue", q0, t_run)
             op.mark_event("reached_pg")
             op.mark_started()
+            # planning only: the backend ends the stage where it takes
+            # the op (its own spans follow as siblings)
+            pg_span = span.stage("pg_do_op")
             try:
-                with span.child("pg_do_op"):
-                    pg.do_op(m, r)
+                pg.do_op(m, r)
             except Exception:
                 # never leak the op as in-flight-forever or leave the
                 # client hanging: fail it with EIO
@@ -1581,6 +1588,7 @@ class OSDDaemon(Dispatcher):
                 reply(-5, None)
                 raise
             finally:
+                pg_span.finish()
                 self.perf.tinc("l_osd_op_trace_pg",
                                time.monotonic() - t_run)
 

@@ -74,7 +74,7 @@ class _Pending:
     async API hands back (result()/done()/exception())."""
 
     __slots__ = ("batch", "event", "out", "error", "trace", "t_submit",
-                 "resident")
+                 "resident", "t_done", "t_wake")
 
     def __init__(self, batch, trace=NULL_SPAN, resident=None):
         self.batch = batch
@@ -84,6 +84,8 @@ class _Pending:
         self.trace = trace if trace is not None else NULL_SPAN
         self.t_submit = time.monotonic()
         self.resident = resident     # (tier, key, codec) adoption ask
+        self.t_done = 0.0            # when its device legs ended
+        self.t_wake = 0.0            # when the dispatcher set the event
 
     # -- future surface ------------------------------------------------
 
@@ -96,9 +98,28 @@ class _Pending:
     def result(self, timeout: float = 120.0):
         if not self.event.wait(timeout=timeout):
             raise TimeoutError("tpu dispatcher wedged")
+        if self.t_wake and self.trace.valid():
+            if self.t_done:
+                # the dispatcher's work after the device legs: result
+                # slicing, HBM-tier adoption, accounting
+                self.trace.child_interval("tpu_finish", self.t_done,
+                                          self.t_wake)
+            # from the event set to this thread running again
+            self.trace.child_interval("tpu_resume", self.t_wake,
+                                      time.monotonic())
+            self.t_wake = 0.0
         if self.error is not None:
             raise self.error
         return self.out
+
+
+def _wake(pend) -> None:
+    """Hand a dispatch's outcome to its submitters: set each one's
+    event, stamped for its tpu_resume span."""
+    now = time.monotonic()
+    for p in pend:
+        p.t_wake = now
+        p.event.set()
 
 
 class _Dispatch:
@@ -916,8 +937,7 @@ class TpuDispatcher:
                 p.error = e
         self._note_dispatch_wall(
             time.monotonic() - min(p.t_submit for p in d.pend))
-        for p in d.pend:
-            p.event.set()
+        _wake(d.pend)
 
     # -- pipelined stages ----------------------------------------------
 
@@ -929,7 +949,7 @@ class TpuDispatcher:
             d.mem_bytes = 0
         for p in d.pend:
             p.error = e
-            p.event.set()
+        _wake(d.pend)
 
     def _h2d_loop(self) -> None:
         prof = self._stage_prof["h2d"]
@@ -1004,8 +1024,7 @@ class TpuDispatcher:
                     d.mem_bytes = 0
             self._note_dispatch_wall(
                 time.monotonic() - min(p.t_submit for p in d.pend))
-            for p in d.pend:
-                p.event.set()
+            _wake(d.pend)
 
     def _run_compute(self, d: _Dispatch):
         """Run the fused program, donating the staged input when safe.
@@ -1141,6 +1160,7 @@ class TpuDispatcher:
             if not p.trace.valid():
                 continue
             p.trace.child_interval("tpu_queue", p.t_submit, d.t_take)
+            p.t_done = d1
             dev = p.trace.child_interval(
                 "tpu_device", h0, d1,
                 batch=int(sum(q.batch.shape[0] for q in d.pend)),

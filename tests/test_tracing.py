@@ -96,6 +96,9 @@ class TestSpanCollector:
         for i in range(6):
             tracer.start_trace("s%d" % i).finish()
         assert [s["name"] for s in tracer.dump()] == ["s3", "s4", "s5"]
+        assert tracer.dropped == 3
+        tracer.clear()
+        assert tracer.dropped == 0
 
     def test_admin_socket_surface(self, tmp_path):
         asok = AdminSocket(str(tmp_path / "t.asok"))
@@ -106,6 +109,7 @@ class TestSpanCollector:
         span.finish()
         doc = asok.execute("dump_tracing")
         assert doc["num_spans"] == 1 and doc["enabled"]
+        assert doc["dropped"] == 0
         # filter by trace id (string form accepted, the CLI spelling)
         doc = asok.execute("dump_tracing",
                            {"trace_id": str(span.trace_id)})
@@ -330,6 +334,91 @@ class TestClusterTracing:
                           if s["name"].startswith("sub_read(shard=")]
             assert len(read_spans) >= 2          # k shards read
             assert any(s["name"] == "ec_decode" for s in all_spans())
+        finally:
+            cluster.stop()
+
+    @pytest.mark.parametrize("case", ["ec_write", "read_stopped_osd"])
+    def test_op_tree_adds_up(self, case):
+        """An OSD's op tree accounts for the op end to end: the new
+        stages are there, children of one span run one after another
+        (only the per-shard siblings overlap), osd_op's own time is at
+        most a tenth of its critical path, and the critical path keeps
+        the backend's stages (ec_encode was once a sibling overlapping
+        pg_do_op, and fell off it)."""
+        from ceph_tpu.mgr.trace_store import critical_path
+
+        from .cluster_util import MiniCluster, wait_until
+        cluster = MiniCluster(num_mons=1, num_osds=5,
+                              conf_overrides=FAST).start()
+        try:
+            client = cluster.client()
+            cluster.create_ec_pool(
+                client, "tree-ec",
+                {"plugin": "jerasure", "technique": "reed_sol_van",
+                 "k": "2", "m": "3", "w": "8"}, pg_num=1)
+            pool = client.pool_id("tree-ec")
+            assert cluster.wait_clean(pool)
+            ioctx = client.open_ioctx("tree-ec")
+            payload = np.random.default_rng(7).bytes(1 << 20)
+            if case == "ec_write":
+                # the first write pays the cluster's cold start
+                ioctx.write_full("warm", payload)
+                want = {"ms_recv", "op_queue", "pg_do_op", "ec_wait",
+                        "ec_encode", "ec_assemble", "tpu_queue",
+                        "tpu_device", "tpu_finish", "tpu_resume",
+                        "ec_txns", "ec_hinfo", "commit_wait",
+                        "ec_sub_write"}
+                backend = {"ec_wait", "ec_encode", "commit_wait"}
+            else:
+                ioctx.write_full("tobj", payload)
+                # stop the OSDs of data shard 1 and of the XOR parity
+                # (shard 2): the read decodes on the device, not by the
+                # host's XOR shortcut
+                m = client.osdmap
+                pgid = m.pools[pool].raw_pg_to_pg(
+                    m.object_to_pg(pool, "tobj"))
+                for shard in (1, 2):
+                    cluster.stop_osd(m.pg_to_up_acting_osds(pgid)[2][shard])
+                assert wait_until(
+                    lambda: ioctx.read("tobj") == payload, timeout=30)
+                want = {"ms_recv", "op_queue", "pg_do_op", "read_gather",
+                        "ec_sub_read", "ec_decode", "tpu_finish",
+                        "tpu_resume"}
+                backend = {"read_gather", "ec_decode"}
+            for osd in cluster.osds.values():
+                osd.tracer.clear()
+            if case == "ec_write":
+                ioctx.write_full("tobj", payload)
+            else:
+                assert ioctx.read("tobj") == payload
+
+            def tree():
+                spans = [s for osd in cluster.osds.values()
+                         for s in osd.tracer.dump()]
+                roots = [s for s in spans if s["name"] == "osd_op"]
+                return [s for s in spans
+                        if s["trace_id"] == roots[0]["trace_id"]] \
+                    if roots else None
+
+            assert wait_until(lambda: tree() is not None)
+            mine = tree()
+            names = {s["name"] for s in mine}
+            assert want <= names, sorted(want - names)
+            kids = {}
+            for s in mine:
+                kids.setdefault(s["parent_id"], []).append(s)
+            for group in kids.values():
+                seq = sorted((s for s in group
+                              if not s["name"].startswith(
+                                  ("sub_write(", "sub_read("))),
+                             key=lambda s: s["start"])
+                for a, b in zip(seq, seq[1:]):
+                    assert a["start"] + a["duration"] <= \
+                        b["start"] + 1e-6, (a["name"], b["name"])
+            path = dict(critical_path(mine))
+            root = next(s for s in mine if s["name"] == "osd_op")
+            assert path["osd_op"] <= 0.1 * root["duration"], path
+            assert backend <= set(path), path
         finally:
             cluster.stop()
 
