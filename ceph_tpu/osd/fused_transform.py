@@ -13,8 +13,10 @@ program over the staged [S, k, chunk] batch:
       exactly what HashInfo/deep-scrub verify against on disk.
 
 One h2d of raw data, one fused program, one d2h of parity + digests +
-compressed payload. The CRC machinery is a GF(2)-linear tree combine:
-per-byte table CRCs are folded 128 lanes at a time, each lane shifted
+compressed payload. The CRC machinery is GF(2)-linear algebra: the
+crc of each 256-byte segment is its 2048 bits times a [2048, 32] bit
+matrix, one int8 matmul on the MXU (ops.xor_mm, as every codec), and
+the segment crcs are folded 128 lanes at a time, each lane shifted
 past its successors by a precomputed 32x32 "append n zero bytes"
 matrix, so the whole digest is O(log L) vectorized levels instead of a
 byte-serial loop, in a lane-major layout the TPU tiles densely. Dynamic
@@ -59,6 +61,7 @@ _POLY_ZLIB = 0xEDB88320   # reflected crc32 (zlib/HashInfo/deep-scrub)
 _POLY_C = 0x82F63B78      # reflected crc32c (Castagnoli)
 _LEVELS = 31              # shift matrices for appends up to 2^30 bytes
 _LANES = 128              # combine width per CRC tree level
+_SEG = 256                # bytes per bit-matrix leaf of the CRC tree
 
 
 def _crc_table(poly: int) -> np.ndarray:
@@ -111,7 +114,8 @@ def _mat_inv(mat: np.ndarray) -> np.ndarray:
 
 class _PolyConsts:
     """Per-polynomial host constants: byte table, append-2^l-zero-bytes
-    matrices (and inverses), built once per process."""
+    matrices (and inverses), segment bit matrices, built once per
+    process."""
 
     def __init__(self, poly: int):
         self.poly = poly
@@ -124,6 +128,7 @@ class _PolyConsts:
         self.shift = np.stack(shifts)              # [.., 32]: append 2^l B
         self.inv = np.stack([_mat_inv(s) for s in shifts])
         self.lanes: dict = {}                      # (seg, q) -> [32, q]
+        self.segs: dict = {}                       # s -> [8s, 32] int8
 
     def _zero_byte_update(self, state: int) -> int:
         return (state >> 8) ^ int(self.table[state & 0xFF])
@@ -154,6 +159,25 @@ class _PolyConsts:
                 mats = self.lanes.setdefault(
                     (seg, q), np.stack(powers[::-1], axis=1))
             return mats
+
+    def seg_mat(self, s: int) -> np.ndarray:
+        """[8s, 32] 0/1 int8: row 8p + b is the crc_raw of the s-byte
+        stream that holds only bit b of byte p, so the crc_raw of any
+        s-byte segment is its bits times this matrix over GF(2). Built
+        by walking the zero-byte update back from the last byte's
+        one-bit CRCs: s steps over the 8 bits."""
+        with _CONSTS_LOCK:
+            mat = self.segs.get(s)
+            if mat is None:
+                rows = np.empty((s, 8), dtype=np.uint32)
+                state = self.table[1 << np.arange(8)]
+                for p in range(s - 1, -1, -1):
+                    rows[p] = state
+                    state = (state >> 8) ^ self.table[state & 0xFF]
+                bits = (rows.reshape(8 * s, 1)
+                        >> np.arange(32, dtype=np.uint32)) & 1
+                mat = self.segs.setdefault(s, bits.astype(np.int8))
+            return mat
 
 
 _CONSTS: dict = {}
@@ -380,14 +404,35 @@ def _combine_lanes(x, mats):
                           (out.ndim - 1,))
 
 
-def _crc_raw_tree(streams, table, pc):
-    """crc_raw (init 0, no xor-out) of each row of streams [..., L]:
-    per-byte table CRCs, then log_128(L) combine levels that each fold
-    128-lane rows of consecutive segments (lane-major, so the device
-    layout stays dense)."""
+def _crc_raw_tree(streams, pc):
+    """crc_raw (init 0, no xor-out) of each row of streams [..., L].
+
+    The leaf is a GF(2) bit-matrix product on the MXU: each row is
+    front-padded with zeros (a crc_raw no-op) to whole _SEG-byte
+    segments, each segment's bits times pc.seg_mat gives its crc_raw
+    (ops.xor_mm's int8 matmul, int32 accumulation, then & 1). Then
+    log_128 combine levels each fold 128-lane rows of consecutive
+    segments (lane-major, so the device layout stays dense)."""
+    import jax
     import jax.numpy as jnp
-    v = table[streams.astype(jnp.int32)]
-    seg = 1
+    from ..ops import xor_mm
+    L = streams.shape[-1]
+    n = -(-L // _SEG)
+    if n * _SEG != L:
+        pad = [(0, 0)] * (streams.ndim - 1) + [(n * _SEG - L, 0)]
+        streams = jnp.pad(streams, pad)
+    shifts = jnp.arange(8, dtype=jnp.uint8)
+    bits = (streams.reshape(streams.shape[:-1] + (n, _SEG, 1))
+            >> shifts) & jnp.uint8(1)                  # [..., n, s, 8]
+    bits = jnp.swapaxes(
+        bits.reshape(bits.shape[:-3] + (n, 8 * _SEG)), -1, -2)
+    crc_bits = xor_mm.xor_matmul(
+        jnp.asarray(pc.seg_mat(_SEG).T), bits)         # [..., 32, n]
+    v = jax.lax.reduce(
+        crc_bits.astype(jnp.uint32)
+        << jnp.arange(32, dtype=jnp.uint32)[:, None],
+        jnp.uint32(0), jax.lax.bitwise_or, (crc_bits.ndim - 2,))
+    seg = _SEG
     while v.shape[-1] > 1:
         n = v.shape[-1]
         q = min(_LANES, n)
@@ -402,12 +447,11 @@ def _crc_raw_tree(streams, table, pc):
     return v[..., 0]
 
 
-def _crc32_full(streams, table, pc, init_const):
+def _crc32_full(streams, pc, init_const):
     """Standard crc32 (init 0xFFFFFFFF, xor-out) of static-length rows.
     init_const = shift_L(0xFFFFFFFF), host-precomputed for the static L."""
     import jax.numpy as jnp
-    return _crc_raw_tree(streams, table, pc) ^ init_const \
-        ^ jnp.uint32(0xFFFFFFFF)
+    return _crc_raw_tree(streams, pc) ^ init_const ^ jnp.uint32(0xFFFFFFFF)
 
 
 def _crc_unshift(crcs, inv, pad_bytes):
@@ -525,6 +569,8 @@ def _build_program(donate: bool):
     def program(data, bitmat, tab_z, inv_z, tab_c,
                 init_chunk_c, init_shard_z, *, w, mode, required_milli,
                 entropy_max_milli, cap2, stripe_width):
+        # tab_z and tab_c are not read (the CRC leaf is a bit-matrix
+        # product); they stay so the positional signature holds
         import jax.numpy as jnp
         S, k, chunk = data.shape
         N = S * k * chunk
@@ -532,7 +578,7 @@ def _build_program(donate: bool):
         pz = _poly_consts(_POLY_ZLIB)
         # (a) per-chunk digests of the RAW chunks
         rows = data.reshape(S * k, chunk)
-        chunk_crc32c = _crc32_full(rows, tab_c, _poly_consts(_POLY_C),
+        chunk_crc32c = _crc32_full(rows, _poly_consts(_POLY_C),
                                    init_chunk_c).reshape(S, k)
         chunk_xxh32 = _xxh32_dev(rows).reshape(S, k)
         if mode == "store":
@@ -540,7 +586,7 @@ def _build_program(donate: bool):
             all_rows = jnp.concatenate([data, parity], axis=1)
             streams = jnp.swapaxes(all_rows, 0, 1).reshape(
                 all_rows.shape[1], S * chunk)
-            shard_crcs = _crc32_full(streams, tab_z, pz, init_shard_z)
+            shard_crcs = _crc32_full(streams, pz, init_shard_z)
             return {"parity": parity, "shard_crcs": shard_crcs,
                     "chunk_crc32c": chunk_crc32c,
                     "chunk_xxh32": chunk_xxh32}
@@ -578,7 +624,7 @@ def _build_program(donate: bool):
             // jnp.int32(stripe_width)
         pad_bytes = ((jnp.int32(S_cap) - used)
                      * jnp.int32(chunk)).astype(jnp.uint32)
-        reg = _crc_raw_tree(streams, tab_z, pz) ^ init_shard_z
+        reg = _crc_raw_tree(streams, pz) ^ init_shard_z
         shard_crcs = _crc_unshift(reg, inv_z, pad_bytes) \
             ^ jnp.uint32(0xFFFFFFFF)
         return {"parity": parity, "stored": stored,
@@ -633,10 +679,8 @@ def device_crc32(data, device=None) -> int:
         cache = _CONSTS.setdefault("scrub_jit", {})
         fn = cache.get(key)
     if fn is None:
-        tab_z = _dev_consts(device)[0]
-
-        def crc_fn(stream, init_c, _t=tab_z, _pc=z):
-            return _crc_raw_tree(stream[None, :], _t, _pc)[0] \
+        def crc_fn(stream, init_c, _pc=z):
+            return _crc_raw_tree(stream[None, :], _pc)[0] \
                 ^ init_c ^ jnp.uint32(0xFFFFFFFF)
 
         from ..common.profiler import PROFILER

@@ -104,6 +104,52 @@ class TestDeviceDigestParity:
             assert r.shard_crcs[i] == zlib.crc32(stream) & 0xFFFFFFFF, i
 
 
+class TestCrcBitMatrixLeaf:
+    """The CRC tree's leaf is a GF(2) bit-matrix product: exact against
+    the host CRCs at lengths below, at and around the 256-byte segment,
+    and it never falls back to a byte-table lookup."""
+
+    @pytest.mark.parametrize("poly", ["zlib", "castagnoli"])
+    @pytest.mark.parametrize("fill", ["random", "ones"])
+    @pytest.mark.parametrize("length", [1, 63, 255, 256, 257, 4096,
+                                        3 * 4096 + 5, 65536])
+    def test_crc_matches_host(self, length, fill, poly):
+        import jax
+        if fill == "random":
+            data = incompressible(length, seed=length)
+        else:
+            data = np.full(length, 0xFF, dtype=np.uint8)
+        if poly == "zlib":
+            pc, want = ft._poly_consts(ft._POLY_ZLIB), zlib.crc32
+        else:
+            pc, want = ft._poly_consts(ft._POLY_C), ft.crc32c_host
+        init = np.uint32(pc.shift_n(0xFFFFFFFF, length))
+        got = jax.jit(lambda x: ft._crc32_full(x[None, :], pc, init))(data)
+        assert int(got[0]) == want(data.tobytes()) & 0xFFFFFFFF
+
+    @pytest.mark.parametrize("mode", ["store", "compress"])
+    def test_program_has_no_gather(self, mode):
+        """A lowered fused program holds no gather: on a TPU a per-byte
+        table lookup moves a whole tile of HBM for each byte looked up."""
+        import jax
+        codec = make_codec()
+        z, c = ft._poly_consts(ft._POLY_ZLIB), ft._poly_consts(ft._POLY_C)
+        S, k, chunk = 4, 2, 256
+        sw = k * chunk
+
+        def spec(a):
+            return jax.ShapeDtypeStruct(np.shape(a), np.asarray(a).dtype)
+        cap2 = S * sw if mode == "store" else ft.plan_capacity(S * sw, sw)
+        text = ft._build_program(False).lower(
+            jax.ShapeDtypeStruct((S, k, chunk), np.uint8),
+            spec(codec._bitmat), spec(z.table), spec(z.inv), spec(c.table),
+            spec(np.uint32(0)), spec(np.uint32(0)), w=8, mode=mode,
+            required_milli=875, entropy_max_milli=7000, cap2=cap2,
+            stripe_width=sw).as_text()
+        assert "dot_general" in text
+        assert "gather" not in text
+
+
 class TestFusedVsSeparate:
     def test_store_mode_parity_equals_separate_encode(self):
         codec = make_codec()
