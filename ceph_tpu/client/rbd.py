@@ -25,14 +25,31 @@ whose header records (parent image, parent snap id): reads fall
 through to the parent's snap for blocks the child hasn't copied; the
 first child write copies the parent block up (copy-up), and flatten()
 severs the dependency.
+
+Data pool (`rbd create --data-pool`, the Luminous layout for images
+on an erasure-coded pool with overwrites): the header, directory,
+object map and journal stay in the image's own (replicated) pool,
+and the data blocks — with the self-managed snap ids that version
+them — live in the data pool the header names.
+
+IO on one handle runs concurrently (librbd's AIO model): the per-handle
+op lock covers only the exclusive-lock check, the object-map
+pre-update and the journal append, and an in-flight count lets the
+exclusive lock's pre-release and the whole-image ops (snapshots,
+resize, flatten) block new IO and drain what is in flight.
 """
 
 from __future__ import annotations
 
+import collections
+import contextlib
 import errno as _errno
 import struct
+import threading
+import time
 
 from .. import encoding
+from ..common.perf_counters import PerfCountersBuilder
 from .striper import FileLayout
 
 
@@ -293,7 +310,9 @@ class RBD:
     @staticmethod
     def create(ioctx, name: str, size: int,
                order: int = DEFAULT_ORDER,
-               features: tuple = ()) -> None:
+               features: tuple = (), data_pool: str | None = None) -> None:
+        """data_pool names the pool of the image's data blocks (an EC
+        pool, typically); None keeps them beside the header."""
         if name in RBD.list(ioctx):
             raise ImageExists(name)
         unknown = set(features) - KNOWN_FEATURES
@@ -319,29 +338,34 @@ class RBD:
                 j.remove()
                 j.create()
             j.register_client("")     # the master position
+        meta = {"snaps": {}, "parent": None, "features": list(features)}
+        if data_pool is not None:
+            meta["data_pool"] = ioctx.client.pool_id(data_pool)
         ioctx.write_full(_header_oid(name),
-                         _pack_header(size, order,
-                                      {"snaps": {}, "parent": None,
-                                       "features": list(features)}))
+                         _pack_header(size, order, meta))
         ioctx.omap_set(DIR_OID, {name: b"1"})
 
     @staticmethod
     def clone(ioctx, parent_name: str, snap_name: str,
-              clone_name: str) -> None:
+              clone_name: str, data_pool: str | None = None) -> None:
         """rbd clone (rbd-layering.rst): a new image COW-backed by the
-        parent's snapshot."""
+        parent's snapshot, whose blocks it reads from the parent's
+        data pool."""
         parent = Image(ioctx, parent_name)
         snap = parent.meta["snaps"].get(snap_name)
         if snap is None:
             raise ImageNotFound("%s@%s" % (parent_name, snap_name))
         if clone_name in RBD.list(ioctx):
             raise ImageExists(clone_name)
+        meta = {"snaps": {},
+                "parent": {"image": parent_name, "snap_id": snap["id"],
+                           "snap_name": snap_name,
+                           "size": snap["size"],
+                           "data_pool": parent.meta.get("data_pool")}}
+        if data_pool is not None:
+            meta["data_pool"] = ioctx.client.pool_id(data_pool)
         ioctx.write_full(_header_oid(clone_name), _pack_header(
-            snap["size"], parent.order,
-            {"snaps": {},
-             "parent": {"image": parent_name, "snap_id": snap["id"],
-                        "snap_name": snap_name,
-                        "size": snap["size"]}}))
+            snap["size"], parent.order, meta))
         ioctx.omap_set(DIR_OID, {clone_name: b"1"})
 
     @staticmethod
@@ -362,7 +386,7 @@ class RBD:
         nblocks = -(-img.size() // img.block_size)
         for b in range(nblocks):
             try:
-                ioctx.remove(_data_oid(name, b))
+                img.data_ioctx.remove(_data_oid(name, b))
             except OSError as e:
                 if not _enoent(e):
                     raise
@@ -394,25 +418,54 @@ class RBD:
 
 
 def _serialized(fn):
-    """Mutating image ops hold the per-handle op lock; the
-    cooperative-handoff release takes the same lock, so the exclusive
-    lock can never be yanked out from under an op already past
-    _ensure_lock (exclusive_lock's pre-release op quiesce)."""
+    """Whole-image ops (snapshots, resize, flatten) run with the
+    handle's IO quiesced and hold its op lock; the cooperative-handoff
+    release does the same, so the exclusive lock can never be yanked
+    out from under an op already past _ensure_lock (exclusive_lock's
+    pre-release op quiesce)."""
     import functools
 
     @functools.wraps(fn)
     def wrapper(self, *args, **kwargs):
-        with self._op_lock:
+        with self._quiesced(), self._op_lock:
             return fn(self, *args, **kwargs)
     return wrapper
+
+
+def _data_ioctx(ioctx, pool_id):
+    """The IoCtx of a data pool by id; None is the image's own pool."""
+    if pool_id is None or pool_id == ioctx.pool_id:
+        return ioctx
+    from .rados import IoCtx
+    return IoCtx(ioctx.client, pool_id)
 
 
 class Image:
     """One open image (librbd Image): offset-addressed block IO."""
 
     def __init__(self, ioctx, name: str, read_only: bool = False):
-        import threading
+        # guards the exclusive-lock check, the object map and the
+        # journal append; data IO runs outside it
         self._op_lock = threading.RLock()
+        # IO in flight on this handle, and the one thread (if any)
+        # that holds it quiesced
+        self._io_cond = threading.Condition()
+        self._inflight = 0
+        self._quiescer = None
+        self._inflight_since = time.monotonic()
+        self.perf = (PerfCountersBuilder("librbd-%s" % name)
+                     .add_u64_counter("l_librbd_rd", "reads completed")
+                     .add_u64_counter("l_librbd_wr", "writes completed")
+                     .add_time("l_librbd_inflight_s",
+                               "time integral of the IOs in flight: its "
+                               "change over a window, divided by the "
+                               "window, is the mean queue depth")
+                     .create_perf_counters())
+        # journal tids appended but not yet committed, in append order
+        self._jorder: collections.deque = collections.deque()
+        self._japplied: set = set()
+        self._jlock = threading.Lock()
+        self._copyup_lock = threading.Lock()
         self.ioctx = ioctx
         self.name = name
         self.read_only = read_only
@@ -425,6 +478,7 @@ class Image:
         if len(hdr) < 9:
             raise ImageNotFound(name)
         self._size, self.order, self.meta = _unpack_header(hdr)
+        self.data_ioctx = _data_ioctx(ioctx, self.meta.get("data_pool"))
         self.block_size = 1 << self.order
         self.layout = FileLayout(self.block_size, 1, self.block_size)
         # journaling feature (librbd RBD_FEATURE_JOURNALING): every
@@ -486,8 +540,8 @@ class Image:
     def _header_notify(self, notify_id, payload):
         """Header watch callback: a contender's request_lock triggers
         the cooperative handoff (exclusive_lock's
-        handle_request_lock) — release after in-flight ops (ops here
-        are synchronous, so immediately) and answer 'released'."""
+        handle_request_lock) — release once new IO is blocked and the
+        in-flight IO has drained, and answer 'released'."""
         try:
             ev = encoding.decode_any(payload) if payload else {}
         except encoding.DecodeError:
@@ -496,14 +550,12 @@ class Image:
                 and self._lock.owned:
             # the callback runs on the messenger reader thread: a
             # synchronous unlock op here would deadlock waiting for
-            # its own reply.  Hand off to a thread — which waits for
-            # any in-flight op (op lock) before releasing — and
+            # its own reply.  Hand off to a thread — which blocks new
+            # IO and drains the in-flight IO before releasing — and
             # answer now; the requester retries until the unlock
             # lands.
-            import threading
-
             def _handoff():
-                with self._op_lock:
+                with self._quiesced(), self._op_lock:
                     self._lock.release()
 
             threading.Thread(target=_handoff, daemon=True).start()
@@ -532,6 +584,65 @@ class Image:
 
     def lock_owned(self) -> bool:
         return self._lock is not None and self._lock.owned
+
+    # -- IO in flight (librbd's AsyncOperation tracking) ----------------
+
+    def _count_inflight(self, step: int) -> None:
+        """Advance the in-flight time integral to now, then move the
+        count by `step` (the caller holds _io_cond)."""
+        now = time.monotonic()
+        self.perf.inc("l_librbd_inflight_s",
+                      self._inflight * (now - self._inflight_since))
+        self._inflight_since = now
+        self._inflight += step
+
+    @contextlib.contextmanager
+    def _io(self, counter: str):
+        """One IO in flight: it waits while another thread holds the
+        handle quiesced, and counts `counter` when it completes."""
+        me = threading.get_ident()
+        with self._io_cond:
+            while self._quiescer not in (None, me):
+                self._io_cond.wait()
+            self._count_inflight(1)
+        try:
+            yield
+            self.perf.inc(counter)
+        finally:
+            with self._io_cond:
+                self._count_inflight(-1)
+                self._io_cond.notify_all()
+
+    @contextlib.contextmanager
+    def _quiesced(self):
+        """Block new IO on this handle and wait for the in-flight IO to
+        drain (exclusive_lock's pre-release block_writes); the holding
+        thread may itself issue IO, and may nest."""
+        me = threading.get_ident()
+        with self._io_cond:
+            if self._quiescer == me:
+                nested = True
+            else:
+                nested = False
+                while self._quiescer is not None:
+                    self._io_cond.wait()
+                self._quiescer = me
+                while self._inflight:
+                    self._io_cond.wait()
+        try:
+            yield
+        finally:
+            if not nested:
+                with self._io_cond:
+                    self._quiescer = None
+                    self._io_cond.notify_all()
+
+    def perf_counters(self) -> dict:
+        """The image's librbd counters, the in-flight integral brought
+        up to now."""
+        with self._io_cond:
+            self._count_inflight(0)
+        return self.perf.dump()
 
     def close(self) -> None:
         if self._map_cb is not None:
@@ -565,7 +676,7 @@ class Image:
         nblocks = -(-self._size // self.block_size)
         for blk in range(nblocks):
             try:
-                self.ioctx.stat(_data_oid(self.name, blk))
+                self.data_ioctx.stat(_data_oid(self.name, blk))
             except OSError as e:
                 if not _enoent(e):
                     raise
@@ -658,16 +769,31 @@ class Image:
         replay)."""
         if self._journal is None or self._replaying:
             return None
-        return self._journal.append("rbd", encoding.encode_any(ev))
+        tid = self._journal.append("rbd", encoding.encode_any(ev))
+        self._jorder.append(tid)       # under the op lock: in tid order
+        return tid
 
     def _journal_commit(self, tid) -> None:
-        if tid is not None:
-            j = self._journal
-            j.commit("", tid)
+        """Mark the event applied; the commit position advances over
+        the longest run of applied events, so concurrent IO finishing
+        out of order never commits past an unapplied one."""
+        if tid is None:
+            return
+        j = self._journal
+        with self._jlock:
+            self._japplied.add(tid)
+            done = []
+            while self._jorder and self._jorder[0] in self._japplied:
+                done.append(self._jorder.popleft())
+                self._japplied.discard(done[-1])
+            if not done:
+                return
+            j.commit("", done[-1])
             # trim only at object-set boundaries: a set becomes
             # removable every splay_width*entries_per_object entries,
             # so per-write trims are pure round-trip overhead
-            if (tid + 1) % (j.splay_width * j.entries_per_object) == 0:
+            per_set = j.splay_width * j.entries_per_object
+            if any((t + 1) % per_set == 0 for t in done):
                 j.trim()
 
     def size(self) -> int:
@@ -676,6 +802,7 @@ class Image:
     def stat(self) -> dict:
         return {"size": self._size, "order": self.order,
                 "block_name_prefix": "rbd_data.%s" % self.name,
+                "data_pool": self.meta.get("data_pool"),
                 "num_objs": -(-self._size // self.block_size),
                 "parent": self.meta.get("parent")}
 
@@ -694,7 +821,7 @@ class Image:
         # image writes carry THIS image's SnapContext (librbd keeps a
         # per-image snap context, not the pool's)
         seq, ids = self._image_snapc()
-        self.ioctx.set_snap_context(seq, ids)
+        self.data_ioctx.set_snap_context(seq, ids)
 
     @_serialized
     def snap_create(self, snap_name: str) -> int:
@@ -703,7 +830,8 @@ class Image:
         self._ensure_lock()
         jtid = self._journal_event({"type": "snap_create",
                                     "name": snap_name})
-        snap_id = self.ioctx.selfmanaged_snap_create()
+        # snap ids version the data blocks: the data pool allocates them
+        snap_id = self.data_ioctx.selfmanaged_snap_create()
         self.meta["snaps"][snap_name] = {"id": snap_id,
                                          "size": self._size}
         self._save_header()
@@ -728,7 +856,7 @@ class Image:
         snap = self.meta["snaps"].pop(snap_name)
         self._save_header()
         # retire the id: OSDs trim the block clones it pinned
-        self.ioctx.selfmanaged_snap_remove(snap["id"])
+        self.data_ioctx.selfmanaged_snap_remove(snap["id"])
         if self._omap is not None:
             try:
                 self.ioctx.remove(_object_map_oid(self.name,
@@ -756,16 +884,16 @@ class Image:
                 if parented:
                     # mask, don't remove: removing would re-expose the
                     # parent's bytes through the COW fall-through
-                    self.ioctx.write(oid, b"\0" * self.block_size, 0)
+                    self.data_ioctx.write(oid, b"\0" * self.block_size, 0)
                     continue
                 try:
-                    self.ioctx.remove(oid)
+                    self.data_ioctx.remove(oid)
                 except OSError as e:
                     if not _enoent(e):
                         raise
                 continue
             try:
-                self.ioctx.rollback_id(oid, snap_id)
+                self.data_ioctx.rollback_id(oid, snap_id)
             except OSError as e:
                 if not _enoent(e):
                     raise    # block absent at snap AND now: nothing
@@ -796,10 +924,10 @@ class Image:
         off = blk * self.block_size
         if off >= parent["size"]:
             return None
+        pio = _data_ioctx(self.ioctx, parent.get("data_pool"))
         try:
-            return self.ioctx.read(_data_oid(parent["image"], blk),
-                                   self.block_size, 0,
-                                   snap=parent["snap_id"])
+            return pio.read(_data_oid(parent["image"], blk),
+                            self.block_size, 0, snap=parent["snap_id"])
         except OSError as e:
             if _enoent(e):
                 return None
@@ -810,9 +938,10 @@ class Image:
         parent's bytes in (librbd copy-up)."""
         data = self._parent_block(blk)
         if data:
-            self.ioctx.write(_data_oid(self.name, blk), data, 0)
+            self.data_ioctx.write(_data_oid(self.name, blk), data, 0)
             if self._omap is not None:
-                self._omap.mark_exists([blk])
+                with self._op_lock:
+                    self._omap.mark_exists([blk])
 
     @_serialized
     def flatten(self) -> None:
@@ -825,14 +954,14 @@ class Image:
         for blk in range(nblocks):
             oid = _data_oid(self.name, blk)
             try:
-                self.ioctx.stat(oid)
+                self.data_ioctx.stat(oid)
                 continue             # child already owns this block
             except OSError as e:
                 if not _enoent(e):
                     raise
             data = self._parent_block(blk)
             if data:
-                self.ioctx.write(oid, data, 0)
+                self.data_ioctx.write(oid, data, 0)
                 if self._omap is not None:
                     self._omap.mark_exists([blk])
         self.meta["parent"] = None
@@ -843,97 +972,102 @@ class Image:
             raise ValueError("extent %d~%d outside image size %d"
                              % (offset, length, self._size))
 
-    @_serialized
+    def _copy_up_if_absent(self, blk: int) -> None:
+        """Before a partial write to a possibly-inherited block: copy
+        the parent bytes up so the rest of the block keeps its COW
+        content (librbd copy-up), once, whatever IO races for it."""
+        with self._copyup_lock:
+            try:
+                self.data_ioctx.stat(_data_oid(self.name, blk))
+            except OSError as e:
+                if not _enoent(e):
+                    raise
+                self._copy_up(blk)
+
     def write(self, offset: int, data: bytes) -> int:
         self._check_extent(offset, len(data))
-        self._ensure_lock()
-        if self._omap is not None:
-            # object map goes EXISTS before the data write lands
-            # (ObjectMap's pre-update ordering: a map that lies
-            # "absent" about a written block corrupts fast-diff; one
-            # that lies "exists" about an absent block only costs a
-            # stat)
-            self._omap.mark_exists(self._omap_blocks(offset,
-                                                     len(data)))
-        jtid = self._journal_event({"type": "write", "offset": offset,
-                                    "data": bytes(data)})
-        self._apply_snapc()
-        parented = self.meta.get("parent") is not None
-        for blk, blk_off, n, foff in self.layout.map_extent(
-                offset, len(data)):
-            oid = _data_oid(self.name, blk)
-            if parented and (blk_off != 0 or n != self.block_size):
-                # partial write to a possibly-inherited block: copy the
-                # parent bytes up first so the rest of the block keeps
-                # its COW content (librbd copy-up)
-                try:
-                    self.ioctx.stat(oid)
-                except OSError as e:
-                    if not _enoent(e):
-                        raise
-                    self._copy_up(blk)
-            self.ioctx.write(oid,
-                             data[foff - offset:foff - offset + n],
-                             blk_off)
-        self._journal_commit(jtid)
+        with self._io("l_librbd_wr"):
+            with self._op_lock:
+                self._ensure_lock()
+                if self._omap is not None:
+                    # object map goes EXISTS before the data write
+                    # lands (ObjectMap's pre-update ordering: a map
+                    # that lies "absent" about a written block
+                    # corrupts fast-diff; one that lies "exists" about
+                    # an absent block only costs a stat)
+                    self._omap.mark_exists(self._omap_blocks(offset,
+                                                             len(data)))
+                jtid = self._journal_event({"type": "write",
+                                            "offset": offset,
+                                            "data": bytes(data)})
+                self._apply_snapc()
+                parented = self.meta.get("parent") is not None
+            for blk, blk_off, n, foff in self.layout.map_extent(
+                    offset, len(data)):
+                if parented and (blk_off != 0 or n != self.block_size):
+                    self._copy_up_if_absent(blk)
+                self.data_ioctx.write(_data_oid(self.name, blk),
+                                      data[foff - offset:foff - offset + n],
+                                      blk_off)
+            self._journal_commit(jtid)
         return len(data)
 
     def read(self, offset: int, length: int) -> bytes:
         self._check_extent(offset, length)
         out = bytearray(length)
-        for blk, blk_off, n, foff in self.layout.map_extent(
-                offset, length):
-            try:
-                piece = self.ioctx.read(_data_oid(self.name, blk),
-                                        n, blk_off)
-            except OSError as e:
-                if not _enoent(e):
-                    raise  # timeout/EIO must not read as zeros
-                # clone: fall through to the parent's snapshot
-                inherited = self._parent_block(blk)
-                piece = (inherited[blk_off:blk_off + n]
-                         if inherited else b"")
-            out[foff - offset:foff - offset + len(piece)] = piece
+        with self._io("l_librbd_rd"):
+            for blk, blk_off, n, foff in self.layout.map_extent(
+                    offset, length):
+                try:
+                    piece = self.data_ioctx.read(_data_oid(self.name, blk),
+                                                 n, blk_off)
+                except OSError as e:
+                    if not _enoent(e):
+                        raise  # timeout/EIO must not read as zeros
+                    # clone: fall through to the parent's snapshot
+                    inherited = self._parent_block(blk)
+                    piece = (inherited[blk_off:blk_off + n]
+                             if inherited else b"")
+                out[foff - offset:foff - offset + len(piece)] = piece
         return bytes(out)
 
-    @_serialized
     def discard(self, offset: int, length: int) -> None:
         """Free whole blocks; zero partial block edges (rbd_discard).
         On a clone, discarded blocks are MASKED with zeros rather than
         removed, or the parent's bytes would resurface."""
         self._check_extent(offset, length)
-        self._ensure_lock()
-        jtid = self._journal_event({"type": "discard", "offset": offset,
-                                    "length": length})
-        self._apply_snapc()
-        parented = self.meta.get("parent") is not None
-        # accumulate touched blocks and flip the object map ONCE at the
-        # end (as write() does): per-block mark+save was O(blocks^2)
-        # map bytes for a large discard
-        absent: list = []
-        exists: list = []
-        for blk, blk_off, n, _ in self.layout.map_extent(offset, length):
-            oid = _data_oid(self.name, blk)
-            if blk_off == 0 and n == self.block_size and not parented:
-                try:
-                    self.ioctx.remove(oid)
-                except OSError as e:
-                    if not _enoent(e):
-                        raise
-                absent.append(blk)
-            else:
-                exists.append(blk)
-                if parented and (blk_off != 0 or n != self.block_size):
+        with self._io("l_librbd_wr"):
+            with self._op_lock:
+                self._ensure_lock()
+                jtid = self._journal_event({"type": "discard",
+                                            "offset": offset,
+                                            "length": length})
+                self._apply_snapc()
+                parented = self.meta.get("parent") is not None
+            # accumulate touched blocks and flip the object map ONCE at
+            # the end (as write() does): per-block mark+save was
+            # O(blocks^2) map bytes for a large discard
+            absent: list = []
+            exists: list = []
+            for blk, blk_off, n, _ in self.layout.map_extent(offset,
+                                                              length):
+                oid = _data_oid(self.name, blk)
+                if blk_off == 0 and n == self.block_size and not parented:
                     try:
-                        self.ioctx.stat(oid)
+                        self.data_ioctx.remove(oid)
                     except OSError as e:
                         if not _enoent(e):
                             raise
-                        self._copy_up(blk)
-                self.ioctx.write(oid, b"\0" * n, blk_off)
-        if self._omap is not None:
-            self._omap.update(exists=exists, absent=absent)
-        self._journal_commit(jtid)
+                    absent.append(blk)
+                else:
+                    exists.append(blk)
+                    if parented and (blk_off != 0 or n != self.block_size):
+                        self._copy_up_if_absent(blk)
+                    self.data_ioctx.write(oid, b"\0" * n, blk_off)
+            if self._omap is not None:
+                with self._op_lock:
+                    self._omap.update(exists=exists, absent=absent)
+            self._journal_commit(jtid)
 
     @_serialized
     def resize(self, new_size: int) -> None:
@@ -950,10 +1084,10 @@ class Image:
                 if parented:
                     # mask, don't remove: a later grow must read zeros
                     # here, not the parent's bytes resurfacing
-                    self.ioctx.write(oid, b"\0" * self.block_size, 0)
+                    self.data_ioctx.write(oid, b"\0" * self.block_size, 0)
                     continue
                 try:
-                    self.ioctx.remove(oid)
+                    self.data_ioctx.remove(oid)
                 except OSError as e:
                     if not _enoent(e):
                         raise
@@ -964,16 +1098,11 @@ class Image:
             if new_size % self.block_size:
                 blk = new_size // self.block_size
                 tail_off = new_size % self.block_size
-                oid = _data_oid(self.name, blk)
                 if parented:
-                    try:
-                        self.ioctx.stat(oid)
-                    except OSError as e:
-                        if not _enoent(e):
-                            raise
-                        self._copy_up(blk)
-                self.ioctx.write(
-                    oid, b"\0" * (self.block_size - tail_off), tail_off)
+                    self._copy_up_if_absent(blk)
+                self.data_ioctx.write(
+                    _data_oid(self.name, blk),
+                    b"\0" * (self.block_size - tail_off), tail_off)
         self._size = new_size
         self._save_header()
         if self._omap is not None:

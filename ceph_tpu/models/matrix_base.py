@@ -161,12 +161,21 @@ class GeneratorCodec(ErasureCode):
                 key, jax.device_put(jnp.asarray(self._bitmat), device))
         return dev
 
-    def _as_device(self, bitmat, entry: dict | None = None, device=None):
+    def _as_device(self, bitmat, entry: dict | None = None, device=None,
+                   data=None):
         """Device copy of a bitmatrix, cached on the encode path or inside
         the decode-cache entry — keyed per HOME device (table_cache
         .device_entry_key), so a repeated erasure signature reuses the
         already-transferred constant on ITS chip and a second pinned
-        device never consumes (or clobbers) the first device's copy."""
+        device never consumes (or clobbers) the first device's copy.
+        Inside a jit trace of the caller (`data` a tracer: the
+        dispatcher jits encode_batch to donate its input) the matrix is
+        a constant of that program and nothing is cached, or a tracer
+        would leak into every later call."""
+        import jax
+        if isinstance(data, jax.core.Tracer):
+            import jax.numpy as jnp
+            return jnp.asarray(bitmat)
         if bitmat is self._bitmat:
             return self._device_bitmat(device)
         import jax.numpy as jnp
@@ -375,7 +384,7 @@ class MatrixErasureCode(GeneratorCodec):
         import jax.numpy as jnp
         from ..ops import xor_mm
         out = xor_mm.matrix_encode(
-            self._as_device(bitmat, entry, _committed_device(data)),
+            self._as_device(bitmat, entry, _committed_device(data), data),
             jnp.asarray(data), self.w)
         return out if _is_jax(data) else np.asarray(out)
 
@@ -438,7 +447,7 @@ class BitmatrixErasureCode(GeneratorCodec):
         import jax.numpy as jnp
         from ..ops import xor_mm
         out = xor_mm.bitmatrix_encode(
-            self._as_device(bitmat, entry, _committed_device(data)),
+            self._as_device(bitmat, entry, _committed_device(data), data),
             jnp.asarray(data), self.w, self.packetsize)
         return out if _is_jax(data) else np.asarray(out)
 

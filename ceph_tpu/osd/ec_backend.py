@@ -67,6 +67,8 @@ class _InflightWrite:
         self.must_read: dict = {}     # oid -> IntervalSet
         self.remote_read_result: dict = {}  # oid -> ExtentMap
         self.pending_reads = 0
+        # the RMW read-back: first sub-read launched, last one done
+        self.t_rmw_start = self.t_rmw_done = 0.0
         self.pending_commits: set = set()   # shard ids
         self.state = "state"          # state -> reads -> commit -> done
 
@@ -199,15 +201,26 @@ class ECBackend:
             self.waiting_state.pop(0)
             op.state = "reads"
             self.waiting_reads.append(op)
-            launch = dict(op.must_read)
+            launch = [(oid, off, length)
+                      for oid, must in op.must_read.items()
+                      for off, length in must]
+            # every read is pending before the first is launched: one
+            # that completes inline must not let the op run ahead
+            op.pending_reads += len(launch)
+        if not launch:
+            return True
         # launch RMW readbacks outside the lock
-        for oid, must in launch.items():
-            for off, length in must:
-                op.pending_reads += 1
-                self._start_read(oid, off, length,
-                                 lambda data, o=op, i=oid, f=off:
-                                 self._rmw_read_done(o, i, f, data),
-                                 internal=True)
+        op.t_rmw_start = time.monotonic()
+        read_bytes = 0
+        for oid, off, length in launch:
+            read_bytes += self._start_read(
+                oid, off, length,
+                lambda data, o=op, i=oid, f=off:
+                self._rmw_read_done(o, i, f, data),
+                internal=True)
+        perf = self.pg.daemon.perf
+        perf.inc("l_osd_ec_rmw_ops")
+        perf.inc("l_osd_ec_rmw_read_bytes", read_bytes)
         return True
 
     def _rmw_read_done(self, op, oid, off, data) -> None:
@@ -215,6 +228,7 @@ class ECBackend:
             if data is not None:
                 self.cache.present_read(oid, off, data)
             op.pending_reads -= 1
+            op.t_rmw_done = time.monotonic()
         self.check_ops()
 
     def _try_reads_to_commit(self) -> bool:
@@ -237,9 +251,16 @@ class ECBackend:
             # try_reads_to_commit is where the codec runs)
             enc_span = op.trace.child("ec_encode")
             if enc_span.valid():
-                # PG order and RMW reads held the op this long
+                # PG order held the op this long; a partial
+                # overwrite's stripe read-back splits the wait in two
                 op.trace.child_interval("ec_wait", op.t_submit,
-                                        enc_span.start)
+                                        op.t_rmw_start or enc_span.start)
+                if op.t_rmw_start:
+                    op.trace.child_interval("ec_rmw_read", op.t_rmw_start,
+                                            op.t_rmw_done)
+                    if enc_span.start > op.t_rmw_done:
+                        op.trace.child_interval("ec_wait", op.t_rmw_done,
+                                                enc_span.start)
             txns, written = ec_transaction.generate_transactions(
                 op.plan, self.codec, self.sinfo, partial,
                 list(range(self.n)), self.pg.cid_of_shard,
@@ -448,17 +469,18 @@ class ECBackend:
         self._start_read(oid, off, length, on_done, trace=trace)
 
     def _start_read(self, oid, off, length, on_done,
-                    internal: bool = False, trace=NULL_SPAN) -> None:
+                    internal: bool = False, trace=NULL_SPAN) -> int:
+        """Returns the chunk bytes the read asked the shards for."""
         size = self._object_logical_size(oid)
         if size == 0:
             on_done(b"" if not internal else None)
-            return
+            return 0
         if length == 0:
             length = max(0, size - off)
         end = min(off + length, size)
         if off >= end:
             on_done(b"")
-            return
+            return 0
         comp = getattr(self.get_hinfo(oid), "comp_info", None)
         if comp is not None:
             # compressed stored stream (fused write transform):
@@ -480,7 +502,7 @@ class ECBackend:
         # serve_reads; the tier invalidates on every mutation and on
         # interval changes, so a hit is always current)
         if self._tier_read(oid, off, end, on_done):
-            return
+            return 0
 
         shards_avail = self.pg.acting_shards()
         # a shard whose OSD is still recovering this object would serve
@@ -494,7 +516,7 @@ class ECBackend:
             to_read = self.codec.minimum_to_decode(want, avail)
         except Exception:
             on_done(None)
-            return
+            return 0
 
         tid = next(self._tids)
         read = _InflightRead(tid, oid, off, end - off, on_done,
@@ -524,6 +546,7 @@ class ECBackend:
                 self.handle_sub_read(msg, local=True)
             else:
                 self.pg.send_to_osd(osd, msg)
+        return chunk_len * len(to_read)
 
     def _object_logical_size(self, oid) -> int:
         return self.get_hinfo(oid).get_total_logical_size(self.sinfo)

@@ -372,6 +372,15 @@ class OSDDaemon(Dispatcher):
                      .add_u64_counter("repaired",
                                       "shards rewritten by read-repair "
                                       "or scrub repair (l_osd_repaired)")
+                     # EC partial overwrites: writes whose stripes the
+                     # primary read back before re-encoding, and the
+                     # chunk bytes it asked the shards for
+                     .add_u64_counter("l_osd_ec_rmw_ops",
+                                      "EC writes that read back their "
+                                      "stripes (read-modify-write)")
+                     .add_u64_counter("l_osd_ec_rmw_read_bytes",
+                                      "chunk bytes read back for EC "
+                                      "read-modify-writes")
                      # recovery/backfill accounting (OSD.cc
                      # l_osd_recovery_ops/_bytes, l_osd_backfill):
                      # incremented per pushed shard on the recovery

@@ -112,6 +112,36 @@ def test_fused_store_program_fits_beside_residency(compile_for, codec,
     assert temp < GB
 
 
+@pytest.mark.parametrize("program", ["rmw_encode", "fused_object"])
+def test_rs_k4m2_rbd_programs_compile(compile_for, program):
+    """The RBD data pool's RS k=4 m=2 programs: the coalesced encode
+    of eight 16 KiB sub-stripe read-modify-writes, and the fused write
+    program of a whole 4 MiB image object (the prefill)."""
+    from ceph_tpu.osd import fused_transform as ft
+    from ceph_tpu.ops import xor_mm
+    k, m = 4, 2
+    rs42 = registry.factory("jax_tpu", {"technique": "reed_sol_van",
+                                        "k": str(k), "m": str(m),
+                                        "w": str(W)})
+    if program == "rmw_encode":
+        compiled = compile_for(xor_mm.matrix_encode.__wrapped__,
+                               ((m * W, k * W), np.uint8),
+                               ((8, k, 4096), np.uint8), w=W)
+    else:
+        z = ft._poly_consts(ft._POLY_ZLIB)
+        c = ft._poly_consts(ft._POLY_C)
+        shape = (256, k, 4096)
+        compiled = compile_for(
+            ft._build_program(False),
+            (shape, np.uint8), (rs42._bitmat.shape, rs42._bitmat.dtype),
+            (z.table.shape, z.table.dtype), (z.inv.shape, z.inv.dtype),
+            (c.table.shape, c.table.dtype), ((), np.uint32),
+            ((), np.uint32), w=W, mode="store", required_milli=875,
+            entropy_max_milli=7000, cap2=256 * k * 4096,
+            stripe_width=k * 4096)
+    assert compiled.memory_analysis().temp_size_in_bytes < GB
+
+
 def test_crush_indep_kernel_compiles(compile_for):
     """The bulk CRUSH indep kernel (x64 fixed-point draws) for a small
     two-level straw2 map: chooseleaf indep 11 over host. Its compile

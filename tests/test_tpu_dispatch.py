@@ -345,6 +345,27 @@ class TestPipeline:
         finally:
             d.shutdown()
 
+    def test_jitted_encode_leaves_the_codec_usable(self):
+        """The donating path jits codec.encode_batch. The codec's cached
+        device matrix must not capture that trace's tracer, or the next
+        batch shape and every un-jitted encode after it fail
+        (UnexpectedTracerError): sub-stripe writes reach the dispatcher
+        in batches of 1 to max_batch stripes."""
+        d = TpuDispatcher(max_batch=1, max_delay=0.0, pipeline_depth=2)
+        d._donate_ok = True           # as on a chip that donates
+        try:
+            codec = _codec()
+            rng = np.random.default_rng(15)
+            for n in (1, 2, 3):
+                batch = rng.integers(0, 256, size=(n, 4, 512),
+                                     dtype=np.uint8)
+                got = np.asarray(d.encode(codec, batch))
+                assert np.array_equal(
+                    got, np.asarray(codec.encode_batch(batch)))
+            assert d.perf.get("l_tpu_donated") == 3
+        finally:
+            d.shutdown()
+
     def test_fake_device_h2d_overlaps_compute(self):
         """Deterministic overlap proof: with the compute stage held
         closed, the h2d stage still stages the NEXT batch — h2d(n+1)
