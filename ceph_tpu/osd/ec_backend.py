@@ -12,10 +12,13 @@ Role of the reference's ECBackend (src/osd/ECBackend.{h,cc}):
           self-delivers (:1998). All-shards-commit completes the op.
   replica handle_sub_write (:917): apply the shard transaction, ack
           with sub_write_committed (:840).
-  read    objects_read_and_reconstruct (:2258): pick min shards via
-          minimum_to_decode (:1488-1556), sub-read chunk extents
-          (handle_sub_read :982 on each shard), reassemble/decode on
-          reply (:1115), complete in order.
+  read    objects_read_and_reconstruct (:2258): a client read asks
+          the data shards of the columns its extent touches (one shard
+          for an extent inside one chunk, all k for a stripe or more;
+          the later releases' partial reads), or the codec's decode set
+          for them via minimum_to_decode (:1488-1556) where one is down,
+          stale or fails; sub-read chunk extents (handle_sub_read :982
+          on each shard), reassemble/decode on reply (:1115).
   recovery  reconstruct a lost shard from k survivors and push it
           (continue_recovery_op :531 reshaped into the PG's recovery
           drive).
@@ -85,8 +88,13 @@ class _InflightRead:
         self.gather_span = NULL_SPAN  # parent of the sub-read spans
         self.sub_spans: dict = {}     # shard -> per-shard sub-read span
         self.raw_shards_cb = None     # recovery: wants raw shard streams
+        self.client = False           # counted in l_osd_ec_read*
+        self.columns = ()             # data columns the extent touches
+        self.want: set = set()        # shards whose bytes the read needs
+        self.exclude: set = set()     # shards never to ask (a rebuild's)
+        self.asked: set = set()       # every shard a sub-read went to
         self.shard_data: dict = {}    # shard -> bytes
-        self.want_shards: set = set()
+        self.want_shards: set = set()  # the decode set it waits for
         self.chunk_off = 0
         self.chunk_len = 0
         self.errors: dict = {}
@@ -461,16 +469,30 @@ class ECBackend:
                      trace=NULL_SPAN) -> None:
         """Async logical read [off, off+length) -> on_done(bytes|None).
 
-        Sub-reads the covering chunk range from the available shards
-        (data shards when whole, any k when degraded), decodes if any
-        data shard is missing, slices the requested range."""
+        Sub-reads the covering chunk range from the data shards of the
+        columns the range touches (their decode set where one is
+        unreadable), decodes if any of them is missing, slices the
+        requested range."""
         if trace is not None:
             trace.end_stage()         # the PG's planning ends here
         self._start_read(oid, off, length, on_done, trace=trace)
 
     def _start_read(self, oid, off, length, on_done,
                     internal: bool = False, trace=NULL_SPAN) -> int:
-        """Returns the chunk bytes the read asked the shards for."""
+        """Plan and send the sub-reads of logical [off, off+length);
+        returns the chunk bytes the read asked the shards for.
+
+        Each sub-read covers the extent's stripes. The shards asked
+        are the data shards of the columns the extent touches: one for
+        an extent inside one chunk, all k for a stripe or more, and
+        all k for a compressed stream, whose logical offsets map to no
+        stored offset. Where one of them is down or stale, the codec
+        picks their decode set (minimum_to_decode). Every codec's data
+        shards hold the logical bytes verbatim (ec_util.encode and
+        encode_fused store the data rows as they are), so no codec
+        needs more. `internal` marks the RMW read-back, which the
+        l_osd_ec_read* counters leave out; its extents are
+        stripe-aligned and ask all k."""
         size = self._object_logical_size(oid)
         if size == 0:
             on_done(b"" if not internal else None)
@@ -488,6 +510,7 @@ class ECBackend:
             # the WHOLE stored stream; completion decompresses + slices
             chunk_off = 0
             chunk_len = self.get_hinfo(oid).get_total_chunk_size()
+            columns = tuple(range(self.k))
         else:
             stripe_off, stripe_len = \
                 self.sinfo.offset_len_to_stripe_bounds((off, end - off))
@@ -495,6 +518,7 @@ class ECBackend:
                 stripe_off)
             chunk_len = self.sinfo.aligned_logical_offset_to_chunk_offset(
                 stripe_len)
+            columns = self.sinfo.columns(off, end - off)
 
         # opt-in residency read: a resident (pg, oid) entry holds the
         # committed full chunk set, so the read is one tiny d2h of the
@@ -504,14 +528,8 @@ class ECBackend:
         if self._tier_read(oid, off, end, on_done):
             return 0
 
-        shards_avail = self.pg.acting_shards()
-        # a shard whose OSD is still recovering this object would serve
-        # STALE bytes — reconstruct around it (peer_missing / the
-        # reference's MissingLoc role)
-        stale = self.pg.osds_missing_object(oid)
-        avail = {s for s, osd in shards_avail.items()
-                 if osd != CRUSH_ITEM_NONE and osd not in stale}
-        want = {self.codec.chunk_index(i) for i in range(self.k)}
+        want = {self.codec.chunk_index(c) for c in columns}
+        shards_avail, avail = self._readable(oid)
         try:
             to_read = self.codec.minimum_to_decode(want, avail)
         except Exception:
@@ -522,31 +540,68 @@ class ECBackend:
         read = _InflightRead(tid, oid, off, end - off, on_done,
                              trace=trace if trace is not None
                              else NULL_SPAN)
+        read.client = not internal
+        read.columns = columns
+        read.want = want
         read.want_shards = set(to_read)
         read.chunk_off = chunk_off
         read.chunk_len = chunk_len
         # first sub-read sent -> last shard in
         read.gather_span = read.trace.child("read_gather")
+        read.gather_span.keyval("shards", len(to_read))
+        if read.client:
+            perf = self.pg.daemon.perf
+            perf.inc("l_osd_ec_reads")
+            if len(columns) < self.k:
+                perf.inc("l_osd_ec_partial_reads")
         with self.lock:
             self.inflight_reads[tid] = read
-        for shard in to_read:
+        self._send_sub_reads(read, to_read, shards_avail)
+        return chunk_len * len(to_read)
+
+    def _readable(self, oid, exclude=()) -> tuple:
+        """(acting shards, the shards a read may ask): those held by an
+        OSD that is up and not still recovering this object (it would
+        serve STALE bytes: peer_missing, the reference's MissingLoc
+        role), less `exclude`."""
+        shards_avail = self.pg.acting_shards()
+        stale = self.pg.osds_missing_object(oid)
+        return shards_avail, {s for s, osd in shards_avail.items()
+                              if osd != CRUSH_ITEM_NONE
+                              and osd not in stale and s not in exclude}
+
+    def _send_sub_reads(self, read, shards, shards_avail: dict,
+                        substituted_for=None) -> None:
+        """One MOSDECSubOpRead of the read's chunk range to each shard,
+        each under its own child span of ``read_gather`` (mirroring the
+        write side's per-shard children). Every shard is in
+        ``read.asked`` before the first is sent: a local sub-read
+        replies inline."""
+        with self.lock:
+            read.asked |= set(shards)
+        if read.client:
+            self.pg.daemon.perf.inc("l_osd_ec_read_shards", len(shards))
+        # remote shards first: the local sub-read replies inline
+        for shard in sorted(shards, key=lambda s: (
+                shards_avail[s] == self.pg.whoami, s)):
             osd = shards_avail[shard]
-            # one child span per shard sub-read, mirroring the write
-            # side's per-shard children
             sub_span = read.gather_span.child("sub_read(shard=%d)" % shard)
             sub_span.keyval("osd", osd)
-            read.sub_spans[shard] = sub_span
+            if substituted_for is not None:
+                sub_span.keyval("substituted_for", substituted_for)
+            with self.lock:
+                read.sub_spans[shard] = sub_span
             t_id, p_id = trace_ctx(sub_span)
             msg = MOSDECSubOpRead(
                 pgid=self.pg.pgid, shard=shard, from_osd=self.pg.whoami,
-                tid=tid, to_read=[(oid, chunk_off, chunk_len, 0)],
+                tid=read.tid,
+                to_read=[(read.oid, read.chunk_off, read.chunk_len, 0)],
                 map_epoch=self.pg.map_epoch(), trace_id=t_id,
                 parent_span=p_id)
             if osd == self.pg.whoami:
                 self.handle_sub_read(msg, local=True)
             else:
                 self.pg.send_to_osd(osd, msg)
-        return chunk_len * len(to_read)
 
     def _object_logical_size(self, oid) -> int:
         return self.get_hinfo(oid).get_total_logical_size(self.sinfo)
@@ -721,6 +776,7 @@ class ECBackend:
     def handle_sub_read_reply(self, msg) -> None:
         bad_oid = None
         done_span = None
+        resend = ()
         with self.lock:
             read = self.inflight_reads.get(msg.tid)
             if read is None:
@@ -729,27 +785,22 @@ class ECBackend:
             if msg.errors:
                 bad_oid = read.oid
                 read.errors[msg.shard] = msg.errors
-                # error on a shard: try to substitute another shard
-                shards_avail = self.pg.acting_shards()
-                stale = self.pg.osds_missing_object(read.oid)
-                avail = {s for s, osd in shards_avail.items()
-                         if osd != CRUSH_ITEM_NONE
-                         and osd not in stale
-                         and s not in read.errors
-                         and s not in read.want_shards}
-                if avail:
-                    sub = min(avail)
-                    read.want_shards.discard(msg.shard)
-                    read.want_shards.add(sub)
-                    resend = (sub, shards_avail[sub])
-                else:
+                # error on a shard: plan the read again without it (the
+                # codec's decode set for the shards the read needs)
+                # and ask the shards that plan adds
+                shards_avail, avail = self._readable(
+                    read.oid, exclude=read.exclude | set(read.errors))
+                try:
+                    read.want_shards = set(self.codec.minimum_to_decode(
+                        read.want, avail))
+                    resend = read.want_shards - read.asked
+                except Exception:
                     self.inflight_reads.pop(msg.tid, None)
                     on_done, read = read.on_done, None
             else:
                 for oid, bufs in msg.buffers_read.items():
                     data = b"".join(b for _off, b in bufs)
                     read.shard_data[msg.shard] = data
-                resend = None
         if done_span is not None:
             if msg.errors:
                 done_span.keyval("error", True)
@@ -766,25 +817,9 @@ class ECBackend:
         if read is None:
             on_done(None)
             return
-        if msg.errors and resend is not None:
-            sub, osd = resend
-            sub_span = read.gather_span.child("sub_read(shard=%d)" % sub)
-            sub_span.keyval("osd", osd)
-            sub_span.keyval("substituted_for", msg.shard)
-            with self.lock:
-                read.sub_spans[sub] = sub_span
-            t_id, p_id = trace_ctx(sub_span)
-            m = MOSDECSubOpRead(
-                pgid=self.pg.pgid, shard=sub, from_osd=self.pg.whoami,
-                tid=msg.tid,
-                to_read=[(read.oid, read.chunk_off, read.chunk_len, 0)],
-                map_epoch=self.pg.map_epoch(), trace_id=t_id,
-                parent_span=p_id)
-            if osd == self.pg.whoami:
-                self.handle_sub_read(m, local=True)
-            else:
-                self.pg.send_to_osd(osd, m)
-            return
+        if resend:
+            self._send_sub_reads(read, resend, shards_avail,
+                                 substituted_for=msg.shard)
         self._maybe_complete_read(msg.tid)
 
     def _maybe_complete_read(self, tid) -> None:
@@ -792,21 +827,24 @@ class ECBackend:
             read = self.inflight_reads.get(tid)
             if read is None:
                 return
-            if set(read.shard_data) != read.want_shards:
+            if not read.want_shards <= set(read.shard_data):
                 return
             self.inflight_reads.pop(tid)
         for span in read.sub_spans.values():
             span.finish()        # stragglers (substituted-away shards)
         read.sub_spans = {}
         read.gather_span.finish()
+        # the plan's shards alone: a straggler a re-plan left out
+        # may have answered too
+        shard_data = {s: read.shard_data[s] for s in read.want_shards}
         if read.raw_shards_cb is not None:
-            read.raw_shards_cb(dict(read.shard_data))
+            read.raw_shards_cb(shard_data)
             return
         # reassemble: decode the chunk streams back to logical bytes
         dec_span = read.trace.child("ec_decode")
         try:
             out = ec_util.decode_concat(
-                self.sinfo, self.codec, dict(read.shard_data),
+                self.sinfo, self.codec, shard_data, columns=read.columns,
                 dispatcher=getattr(self.pg.daemon, "tpu_dispatcher",
                                    None),
                 trace=dec_span)
@@ -883,11 +921,7 @@ class ECBackend:
                            chunk_total: int, on_done) -> None:
         """Full-survivor recovery: read k whole chunk streams and
         decode (the classic path; also the repair path's fallback)."""
-        shards_avail = self.pg.acting_shards()
-        stale = self.pg.osds_missing_object(oid)
-        avail = {s for s, osd in shards_avail.items()
-                 if osd != CRUSH_ITEM_NONE and s != target_shard
-                 and osd not in stale}
+        shards_avail, avail = self._readable(oid, exclude={target_shard})
         tid = next(self._tids)
         read = _InflightRead(tid, oid, 0, 0, None)
         # the codec picks the repair set: for RS any k survivors, for
@@ -902,6 +936,7 @@ class ECBackend:
         if not use:
             on_done(None)
             return
+        read.want, read.exclude = {target_shard}, {target_shard}
         read.want_shards = set(use)
         read.chunk_off = 0
         read.chunk_len = chunk_total
@@ -937,16 +972,7 @@ class ECBackend:
         read.on_done = lambda _data: on_done(None)  # error path only
         with self.lock:
             self.inflight_reads[tid] = read
-        for shard in use:
-            osd = shards_avail[shard]
-            msg = MOSDECSubOpRead(
-                pgid=self.pg.pgid, shard=shard, from_osd=self.pg.whoami,
-                tid=tid, to_read=[(oid, 0, chunk_total, 0)],
-                map_epoch=self.pg.map_epoch())
-            if osd == self.pg.whoami:
-                self.handle_sub_read(msg, local=True)
-            else:
-                self.pg.send_to_osd(osd, msg)
+        self._send_sub_reads(read, use, shards_avail)
 
     # =================================================================
     # regenerating-code repair (beta-fraction helper reads)
@@ -964,14 +990,6 @@ class ECBackend:
         except KeyError:
             pass
 
-    def _repair_helpers_avail(self, oid, target_shard: int) -> tuple:
-        shards_avail = self.pg.acting_shards()
-        stale = self.pg.osds_missing_object(oid)
-        avail = {s for s, osd in shards_avail.items()
-                 if osd != CRUSH_ITEM_NONE and s != target_shard
-                 and osd not in stale}
-        return shards_avail, avail
-
     def _try_repair(self, oid, target_shard: int, chunk_total: int,
                     on_done) -> bool:
         """Launch a beta-fraction repair when the codec supports it and
@@ -988,8 +1006,7 @@ class ECBackend:
             pass
         if chunk_total % self.sinfo.chunk_size:
             return False
-        shards_avail, avail = self._repair_helpers_avail(oid,
-                                                         target_shard)
+        shards_avail, avail = self._readable(oid, exclude={target_shard})
         try:
             helpers = codec.minimum_to_repair(target_shard, avail)
         except Exception:
@@ -1071,8 +1088,8 @@ class ECBackend:
                 bad = msg.shard in rep.helpers
                 rep.helpers.discard(msg.shard)
                 rep.fractions.pop(msg.shard, None)
-                shards_avail, avail = self._repair_helpers_avail(
-                    rep.oid, rep.target_shard)
+                shards_avail, avail = self._readable(
+                    rep.oid, exclude={rep.target_shard})
                 candidates = avail - rep.tried
                 if candidates:
                     sub = min(candidates)

@@ -381,6 +381,17 @@ class OSDDaemon(Dispatcher):
                      .add_u64_counter("l_osd_ec_rmw_read_bytes",
                                       "chunk bytes read back for EC "
                                       "read-modify-writes")
+                     # EC client reads: each asks the data shards of
+                     # the columns its extent touches (ec_backend)
+                     .add_u64_counter("l_osd_ec_reads",
+                                      "EC client reads planned")
+                     .add_u64_counter("l_osd_ec_read_shards",
+                                      "shards the EC client reads "
+                                      "asked")
+                     .add_u64_counter("l_osd_ec_partial_reads",
+                                      "EC client reads whose extent "
+                                      "touched fewer than k data "
+                                      "shards")
                      # recovery/backfill accounting (OSD.cc
                      # l_osd_recovery_ops/_bytes, l_osd_backfill):
                      # incremented per pushed shard on the recovery

@@ -265,7 +265,8 @@ class TestClusterTracing:
                  "k": "2", "m": "1", "w": "8"}, pg_num=1)
             assert cluster.wait_clean(client.pool_id("trace-ec"))
             ioctx = client.open_ioctx("trace-ec")
-            payload = bytes(range(256)) * 16
+            # one whole stripe (2 x 4 KiB): reading it back asks k shards
+            payload = bytes(range(256)) * 32
             ioctx.write_full("tobj", payload)
             assert ioctx.read("tobj") == payload
 
