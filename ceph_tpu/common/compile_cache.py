@@ -1,9 +1,10 @@
 """Where JAX keeps its persistent compile cache.
 
 The TPU programs of the write path take seconds to minutes to compile
-cold, so every entry point (vstart, the EC benchmark tool, bench.py's
-worker, chip_smoke.py) calls `configure_compile_cache()` once at start
-and a second run of the same program loads what the first compiled.
+cold, so the command-line entry points (`tools/vstart.py` and
+`tools/erasure_code_benchmark.py`) call `configure_compile_cache()`
+once at start and a second run of the same program loads what the
+first compiled.
 Importing a module never touches the cache.
 
 When `JAX_COMPILATION_CACHE_DIR` is set, JAX reads it itself and this

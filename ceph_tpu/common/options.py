@@ -115,11 +115,6 @@ def _declare_defaults():
       "jax.local_devices() (modulo the device count); -1 = round-robin "
       "by osd id, so an 8-OSD MiniCluster on an 8-chip mesh lands one "
       "OSD per chip without per-daemon conf")
-    o("osd_tpu_pipeline_depth", int, 2, LEVEL_ADVANCED,
-      "fused batches in flight per dispatcher pipeline stage: h2d of "
-      "batch n+1 overlaps compute of n and d2h of n-1 "
-      "(osd/tpu_dispatch.py staging ring); 1 = the legacy synchronous "
-      "coalesce-then-block loop")
     o("osd_mesh_rateless", bool, True, LEVEL_ADVANCED,
       "route bulk mesh encode/decode/repair-combine jobs through the "
       "rateless micro-batch work queue (parallel/rateless.py; ROADMAP "

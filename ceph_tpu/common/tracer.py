@@ -26,9 +26,6 @@ Pieces:
                  fragments until the verdict and dropped traces cost
                  zero wire bytes.
   trace_ctx      (trace_id, parent_span_id) for a message envelope.
-  device_segments  run a codec call split into h2d / compute / d2h
-                 segments (the TpuDispatcher's synchronous path times
-                 its device spans with it).
   render_tree    the `ceph trace tree` renderer: stitched cross-daemon
                  span tree with per-span self-times.
 """
@@ -42,11 +39,9 @@ import threading
 import time
 from collections import deque
 
-import numpy as np
-
 __all__ = ["Span", "NULL_SPAN", "SpanCollector", "TailSampler",
            "parse_slo_targets", "trace_ctx", "wire_span",
-           "device_segments", "render_tree"]
+           "render_tree"]
 
 # span ids must be unique ACROSS daemons for one trace (shards' spans
 # from different OSDs land in one tree): a per-process random high part
@@ -506,33 +501,6 @@ class TailSampler:
         if now - self._last_sweep >= 1.0:
             self._last_sweep = now
             self.sweep(now)
-
-
-# -- shared device-call segmentation -----------------------------------
-
-def device_segments(fn, batch):
-    """Run fn(batch) as an explicit h2d -> compute -> d2h sequence and
-    time each leg.  Returns (host ndarray result, {"h2d", "compute",
-    "d2h"} seconds).  Falls back to one unsegmented call (all time
-    under "compute") when jax is absent."""
-    t0 = time.perf_counter()
-    try:
-        import jax
-        import jax.numpy as jnp
-    except Exception:
-        out = fn(batch)
-        out = out if isinstance(out, dict) else np.asarray(out)
-        return out, {"h2d": 0.0, "compute": time.perf_counter() - t0,
-                     "d2h": 0.0}
-    dev = jax.block_until_ready(jnp.asarray(batch))
-    t1 = time.perf_counter()
-    out_dev = jax.block_until_ready(fn(dev))
-    t2 = time.perf_counter()
-    # fused programs return an output dict; drain it in ONE device_get
-    out = jax.device_get(out_dev) if isinstance(out_dev, dict) \
-        else np.asarray(out_dev)
-    t3 = time.perf_counter()
-    return out, {"h2d": t1 - t0, "compute": t2 - t1, "d2h": t3 - t2}
 
 
 # -- tree rendering (the `ceph trace tree` surface) --------------------

@@ -236,7 +236,6 @@ class OSDDaemon(Dispatcher):
                 max_delay=conf.get_val(
                     "osd_tpu_coalesce_max_delay_ms") / 1e3,
                 tracer=self.tracer,
-                pipeline_depth=conf.get_val("osd_tpu_pipeline_depth"),
                 device=self.home_device)
             # l_tpu_* device-segment counters ride the daemon's perf
             # collection (mgr report -> prometheus)

@@ -44,8 +44,8 @@ failed stage fails ONLY that fused batch's submitters; batches behind
 it keep flowing.
 
 Knobs ride the options schema: osd_tpu_coalesce (default on),
-osd_tpu_coalesce_max_batch, osd_tpu_coalesce_max_delay_ms,
-osd_tpu_pipeline_depth (1 = the legacy synchronous loop).
+osd_tpu_coalesce_max_batch, osd_tpu_coalesce_max_delay_ms. The ring
+depth is a constructor argument (default 2, at least 2).
 """
 
 from __future__ import annotations
@@ -59,7 +59,7 @@ import numpy as np
 
 from ..common.perf_counters import PerfCountersBuilder
 from ..common.profiler import PROFILER
-from ..common.tracer import NULL_SPAN, device_segments
+from ..common.tracer import NULL_SPAN
 
 __all__ = ["TpuDispatcher"]
 
@@ -199,12 +199,6 @@ class _StageProf:
             self.state = state
             self.since = now
 
-    def credit(self, state: str, seconds: float) -> None:
-        """Direct accrual without a state switch (the depth-1 inline
-        path, which runs every leg on the collector thread)."""
-        with self.lock:
-            self.acc[state] += max(0.0, seconds)
-
     def snapshot(self, now: float | None = None) -> dict:
         now = time.monotonic() if now is None else now
         with self.lock:
@@ -266,13 +260,10 @@ class TpuDispatcher:
 
     Observability: with a tracer whose collection is enabled, each
     submitter's span grows a queue-delay child plus a device span split
-    into h2d / compute / d2h segments. In pipelined mode the segments
-    are the MEASURED stage intervals (monotonic stamps), so spans from
-    consecutive dispatches visibly overlap — the regression evidence
-    bench.py gates on. The l_tpu_* PerfCounters aggregate the same
-    segments. With pipelining off and tracing off the dispatch path is
-    byte-for-byte the historical one: no extra device syncs, no span
-    allocation.
+    into h2d / compute / d2h segments. The segments are the MEASURED
+    stage intervals (monotonic stamps), so spans from consecutive
+    dispatches visibly overlap. The l_tpu_* PerfCounters aggregate the
+    same segments; stamping them costs no extra device sync.
     """
 
     def __init__(self, max_batch: int = 8, max_delay: float = 0.002,
@@ -281,7 +272,10 @@ class TpuDispatcher:
         self.max_delay = max_delay
         self.tracer = tracer
         self.device = device        # home device (None = implicit default)
-        self.pipeline_depth = max(1, int(pipeline_depth))
+        if int(pipeline_depth) < 2:
+            raise ValueError("pipeline_depth must be at least 2, got %r"
+                             % (pipeline_depth,))
+        self.pipeline_depth = int(pipeline_depth)
         self.lock = threading.Lock()
         self.cv = threading.Condition(self.lock)
         self.queues: dict = {}     # key -> (fn, [_Pending])
@@ -364,20 +358,18 @@ class TpuDispatcher:
         self._profile_t0 = time.monotonic()
         self._stop = False
         self._threads: list = []
-        if self.pipeline_depth > 1:
-            # the staging ring: bounded hand-off queues between stages.
-            # depth bounds how many fused batches are in flight per
-            # stage; the collector blocks when the ring is full.
-            self._q_h2d: queue.Queue = _RingQueue(self.pipeline_depth)
-            self._q_compute: queue.Queue = _RingQueue(
-                self.pipeline_depth)
-            self._q_d2h: queue.Queue = _RingQueue(self.pipeline_depth)
-            for name, fn in (("tpu-h2d", self._h2d_loop),
-                             ("tpu-compute", self._compute_loop),
-                             ("tpu-d2h", self._d2h_loop)):
-                t = threading.Thread(target=fn, name=name, daemon=True)
-                t.start()
-                self._threads.append(t)
+        # the staging ring: bounded hand-off queues between stages.
+        # depth bounds how many fused batches are in flight per stage;
+        # the collector blocks when the ring is full.
+        self._q_h2d: queue.Queue = _RingQueue(self.pipeline_depth)
+        self._q_compute: queue.Queue = _RingQueue(self.pipeline_depth)
+        self._q_d2h: queue.Queue = _RingQueue(self.pipeline_depth)
+        for name, fn in (("tpu-h2d", self._h2d_loop),
+                         ("tpu-compute", self._compute_loop),
+                         ("tpu-d2h", self._d2h_loop)):
+            t = threading.Thread(target=fn, name=name, daemon=True)
+            t.start()
+            self._threads.append(t)
         self._thread = threading.Thread(
             target=self._run, name="tpu-dispatch", daemon=True)
         self._thread.start()
@@ -690,14 +682,11 @@ class TpuDispatcher:
     def dispatch_status(self) -> dict:
         """The `dispatch status` asok payload: pipeline shape, ring
         occupancy per stage, and the coalescing ledger."""
-        ring = {"staging": 0, "computing": 0, "draining": 0}
-        if self.pipeline_depth > 1:
-            ring = {"staging": self._q_h2d.qsize(),
-                    "computing": self._q_compute.qsize(),
-                    "draining": self._q_d2h.qsize()}
+        ring = {"staging": self._q_h2d.qsize(),
+                "computing": self._q_compute.qsize(),
+                "draining": self._q_d2h.qsize()}
         tel = self.telemetry()
         return {"pipeline_depth": self.pipeline_depth,
-                "overlapped": self.pipeline_depth > 1,
                 "device": tel["device"],
                 "ring": ring,
                 "queue_depth": tel["queue_depth"],
@@ -742,15 +731,11 @@ class TpuDispatcher:
             for state in _STATES:
                 self.perf.set("l_tpu_stage_%s_%s" % (name, state),
                               acc[state])
-        occupancy = {"staging": 0.0, "computing": 0.0, "draining": 0.0}
-        if self.pipeline_depth > 1:
-            occupancy = {
-                "staging": round(
-                    self._q_h2d.occupancy_integral() / wall, 4),
-                "computing": round(
-                    self._q_compute.occupancy_integral() / wall, 4),
-                "draining": round(
-                    self._q_d2h.occupancy_integral() / wall, 4)}
+        occupancy = {
+            "staging": round(self._q_h2d.occupancy_integral() / wall, 4),
+            "computing": round(
+                self._q_compute.occupancy_integral() / wall, 4),
+            "draining": round(self._q_d2h.occupancy_integral() / wall, 4)}
         device = ("h2d", "compute", "d2h")
         bound = max(device, key=lambda s: stages[s]["busy_frac"])
         attribution = stages[bound]["busy_frac"]
@@ -776,18 +761,16 @@ class TpuDispatcher:
         for prof in self._stage_prof.values():
             prof.reset(now)
         self._profile_t0 = now
-        if self.pipeline_depth > 1:
-            self._q_h2d.occupancy_reset()
-            self._q_compute.occupancy_reset()
-            self._q_d2h.occupancy_reset()
+        self._q_h2d.occupancy_reset()
+        self._q_compute.occupancy_reset()
+        self._q_d2h.occupancy_reset()
 
     def shutdown(self) -> None:
         with self.cv:
             self._stop = True
             self.cv.notify_all()
-        if self.pipeline_depth > 1:
-            # sentinels flush the stage threads in order
-            self._q_h2d.put(None)
+        # sentinels flush the stage threads in order
+        self._q_h2d.put(None)
         for t in self._threads:
             t.join(timeout=5)
 
@@ -867,12 +850,9 @@ class TpuDispatcher:
                     deadline = time.monotonic() + wait
                 self.cv.wait(wait)
 
-    def _instrumenting(self) -> bool:
-        return self.tracer is not None and self.tracer.enabled
-
     def _run(self):
         """Collector: group submitters into fused dispatches and feed
-        the pipeline (or, depth 1, run the legacy synchronous loop)."""
+        the pipeline."""
         prof = self._stage_prof["collector"]
         while True:
             # idle = waiting for submitters (or stragglers): a starved
@@ -888,56 +868,10 @@ class TpuDispatcher:
             if len(d.pend) > 1:
                 self.stats["coalesced"] += len(d.pend)
                 self.perf.inc("l_tpu_coalesced", len(d.pend))
-            if self.pipeline_depth > 1:
-                # blocks when the staging ring is full: that back-
-                # pressure IS the depth-N bound
-                prof.enter("blocked")
-                self._q_h2d.put(d)
-            else:
-                self._dispatch_inline(d)
-
-    # -- legacy (depth-1) synchronous path ------------------------------
-
-    def _dispatch_inline(self, d: _Dispatch) -> None:
-        instrument = self._instrumenting()
-        t_start = time.monotonic()
-        try:
-            stacked = d.pend[0].batch if len(d.pend) == 1 \
-                else np.concatenate([p.batch for p in d.pend])
-            if instrument:
-                # explicit h2d/compute/d2h segmentation (two extra
-                # device syncs — the disabled path never pays them)
-                out, seg = device_segments(d.fn, stacked)
-            else:
-                out = d.fn(stacked)
-                # fused programs return an output dict: drain it in one
-                # transfer instead of np-coercing it
-                out = self._devops.d2h(out) if isinstance(out, dict) \
-                    else np.asarray(out)
-                seg = None
-            self._slice_results(d, out)
-            self._adopt_residents(d, stacked, out)
-            if d.kind == "fused":
-                self._account_fused(d)
-            if seg is not None:
-                t1 = t_start + seg["h2d"]
-                t2 = t1 + seg["compute"]
-                d.seg = {"h2d": (t_start, t1), "compute": (t1, t2),
-                         "d2h": (t2, t2 + seg["d2h"])}
-                self._account(d)
-                # depth-1 runs every leg on the collector thread; the
-                # per-stage machines never switch state, so credit the
-                # measured segments directly (attribution still works
-                # on the legacy synchronous path when instrumented)
-                for stage in ("h2d", "compute", "d2h"):
-                    a, b = d.seg[stage]
-                    self._stage_prof[stage].credit("busy", b - a)
-        except BaseException as e:   # deliver, don't kill the loop
-            for p in d.pend:
-                p.error = e
-        self._note_dispatch_wall(
-            time.monotonic() - min(p.t_submit for p in d.pend))
-        _wake(d.pend)
+            # blocks when the staging ring is full: that back-pressure
+            # IS the depth-N bound
+            prof.enter("blocked")
+            self._q_h2d.put(d)
 
     # -- pipelined stages ----------------------------------------------
 
@@ -1091,9 +1025,8 @@ class TpuDispatcher:
                          ) -> None:
         """Hand the staged data rows + computed parity rows to the HBM
         tier for any submitter that asked — the arrays are already
-        device-side in pipelined mode, so residency costs ZERO extra
-        transfers. Adoption failures never fail the submitter (the tier
-        is a cache)."""
+        device-side, so residency costs ZERO extra transfers. Adoption
+        failures never fail the submitter (the tier is a cache)."""
         if d.kind == "fused":
             # one submitter per fused dispatch (the key is unique):
             # adopt what was actually STORED — the compressed rows when
@@ -1141,10 +1074,10 @@ class TpuDispatcher:
         """Fold one dispatch's measured stage intervals into the
         l_tpu_* counters and back-fill queue/device spans under every
         participating op's trace (the segments are shared: a fused
-        dispatch ran once for all of them). In pipelined mode the
-        intervals are REAL wall stamps, so spans from consecutive
-        dispatches overlap — that overlap is the proof the pipeline
-        works, and bench.py gates on it."""
+        dispatch ran once for all of them). The intervals are REAL
+        wall stamps, so spans from consecutive dispatches overlap —
+        that overlap is the proof the pipeline works
+        (tests/test_tpu_dispatch.py checks it)."""
         seg = d.seg
         if not seg:
             return

@@ -1,9 +1,9 @@
 """Test env: force the CPU backend with a virtual 8-device mesh.
 
 Tests never require TPU hardware; sharding logic is validated on a
-virtual 8-device CPU platform. What runs on the chip is proven by
-chip_smoke.py, and tests/test_chip_compile.py compiles the main
-kernels for a described (not attached) TPU v5e.
+virtual 8-device CPU platform. What runs on the chip is measured by
+`python3 -m benchmark.run`, and tests/test_chip_compile.py compiles
+the main kernels for a described (not attached) TPU v5e.
 
 This IS the CPU-CI fake-mesh recipe (README "Mesh-native cluster"):
 

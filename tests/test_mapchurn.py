@@ -644,8 +644,7 @@ class TestHugeMapConvergence:
         """Scale leg: a 1000-OSD map balances within a bounded round
         count, never violating the rule's failure-domain separation
         (sampled).  The bulk sweeps run the compiled host mapper (the
-        honest comparator on a CPU-only host — cf. bench.py's CRUSH
-        row); a sampled mesh_do_rule pass gates that the mesh-sharded
+        honest comparator on a CPU-only host); a sampled mesh_do_rule pass gates that the mesh-sharded
         device sweep is bit-identical on the SAME balanced map, so on
         real hardware the full-width sweep is interchangeable."""
         from ceph_tpu.crush.batched import mesh_do_rule

@@ -393,6 +393,32 @@ class TestLiveAttribution:
         assert ("client", "pool") in key_bys
         assert ("pool",) in key_bys
 
+    def test_attributed_bytes_match_op_in_bytes(self, pq_cluster):
+        """The (client, pool) tables attribute at least 95% of the
+        client bytes the OSDs themselves count in op_in_bytes."""
+        cluster, _, client, _ = pq_cluster
+        osds = list(cluster.osds.values())
+        assert wait_until(lambda: all(o.perf_query.active
+                                      for o in osds), timeout=15)
+
+        def totals():
+            attributed = 0
+            for o in osds:
+                for table in o.perf_query.dump().values():
+                    if table["key_by"] == ["client", "pool"]:
+                        attributed += sum(
+                            r["wr_bytes"] for r in table["keys"]
+                            if r["k"][1] == "attrpool")
+            return attributed, sum(o.perf.get("op_in_bytes")
+                                   for o in osds)
+        a0, b0 = totals()
+        _load(client, n=24)
+
+        def faithful():
+            a1, b1 = totals()
+            return b1 - b0 >= 24 * 4096 and a1 - a0 >= 0.95 * (b1 - b0)
+        assert wait_until(faithful, timeout=10, interval=0.2)
+
     def test_iotop_attributes_live_load(self, pq_cluster):
         cluster, mgr, client, _ = pq_cluster
         _load(client)

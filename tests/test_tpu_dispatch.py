@@ -419,7 +419,6 @@ class TestPipeline:
             assert dump["l_tpu_d2h"]["avgcount"] >= 1
             status = d.dispatch_status()
             assert status["pipeline_depth"] == 2
-            assert status["overlapped"] is True
             assert set(status["ring"]) == {"staging", "computing",
                                            "draining"}
             assert status["dispatches"] >= 1
@@ -427,24 +426,12 @@ class TestPipeline:
         finally:
             d.shutdown()
 
-    def test_depth_one_keeps_legacy_synchronous_path(self):
-        """pipeline_depth=1 is the historical coalesce-then-block
-        loop: correct results, no stage threads, no segment samples
-        without a tracer."""
-        d = TpuDispatcher(max_batch=4, max_delay=0.001,
-                          pipeline_depth=1)
-        try:
-            codec = _codec()
-            rng = np.random.default_rng(16)
-            batch = rng.integers(0, 256, size=(2, 4, 512),
-                                 dtype=np.uint8)
-            out = np.asarray(d.encode(codec, batch))
-            assert np.array_equal(out, np.asarray(
-                codec.encode_batch(batch)))
-            assert d.perf.dump()["l_tpu_h2d"]["avgcount"] == 0
-            assert d.dispatch_status()["overlapped"] is False
-        finally:
-            d.shutdown()
+    @pytest.mark.parametrize("depth", [0, 1])
+    def test_depth_below_two_is_refused(self, depth):
+        """The staging ring is the only dispatch loop: a ring shallower
+        than two batches per stage cannot overlap, so it is refused."""
+        with pytest.raises(ValueError, match="pipeline_depth"):
+            TpuDispatcher(pipeline_depth=depth)
 
 class _SleepyDevOps:
     """Deterministic fake device with a configurable latency per stage,

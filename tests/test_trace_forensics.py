@@ -220,6 +220,40 @@ class TestCriticalPath:
         assert critical_path([]) == []
 
 
+class TestTraceStoreBudget:
+    def test_flood_of_ten_budgets_stays_within_budget(self):
+        """The mgr store's byte budget is hard: ten budgets' worth of
+        kept traces never leave more than the budget tracked, and the
+        slowest traces are the ones retained."""
+        from ceph_tpu.mgr.trace_store import TraceModule
+        conf = {"mgr_trace_store_bytes": 2000,
+                "mgr_trace_protect_slowest": 4}
+        mgr = types.SimpleNamespace(ctx=types.SimpleNamespace(
+            conf=types.SimpleNamespace(get_val=conf.__getitem__)))
+        store = TraceModule(mgr)
+        try:
+            n = 200
+            for i in range(1, n + 1):
+                span = {"span_id": i, "parent_id": 0, "name": "osd_op",
+                        "endpoint": "osd.0", "start": 0.0,
+                        "duration": i * 1e-3, "keyvals": {},
+                        "events": []}
+                store._ingest(types.SimpleNamespace(
+                    trace_id=i, spans=[span], anchor_wall=0.0,
+                    anchor_mono=0.0, pool="flood", op_type="write",
+                    reason="slo", duration=i * 1e-3,
+                    daemon_name="osd.0"))
+                assert store.status()["tracked_bytes"] <= 2000
+            st = store.status()
+            assert st["ingested_bytes"] >= 10 * 2000
+            assert st["evicted"] == n - st["retained"]
+            kept = {int(r["trace_id"], 16)
+                    for r in store.slowest("flood", count=4)["slowest"]}
+            assert kept == set(range(n - 3, n + 1))
+        finally:
+            store.shutdown()
+
+
 class TestRenderTreeWallOrder:
     def test_siblings_order_by_wall_not_monotonic(self):
         # cross-process siblings: the replica's monotonic start (5.0)

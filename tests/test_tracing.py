@@ -17,8 +17,7 @@ import pytest
 from ceph_tpu.common.admin_socket import AdminSocket
 from ceph_tpu.common.config import Config
 from ceph_tpu.common.tracer import (NULL_SPAN, SpanCollector,
-                                    device_segments, render_tree,
-                                    trace_ctx)
+                                    render_tree, trace_ctx)
 
 FAST = {"osd_heartbeat_interval": 0.1, "osd_heartbeat_grace": 0.6,
         "mon_osd_down_out_interval": 1.0,
@@ -140,19 +139,6 @@ class TestSpanCollector:
         assert render_tree([]) == "(no spans)"
 
 
-class TestDeviceSegments:
-    def test_segments_sum_within_wall(self):
-        batch = np.arange(64, dtype=np.uint8).reshape(1, 4, 16)
-        t0 = time.perf_counter()
-        out, seg = device_segments(
-            lambda b: np.asarray(b, dtype=np.uint8) ^ 0xFF, batch)
-        wall = time.perf_counter() - t0
-        assert np.array_equal(out, batch ^ 0xFF)
-        assert set(seg) == {"h2d", "compute", "d2h"}
-        assert all(v >= 0 for v in seg.values())
-        assert sum(seg.values()) <= wall * 1.05 + 1e-4
-
-
 class _XorCodec:
     """Tiny stand-in codec: encode_batch works on host or device."""
 
@@ -189,29 +175,18 @@ class TestDispatcherTracing:
             disp.shutdown()
 
     def test_disabled_tracer_no_spans_no_segments(self):
-        """Disabled tracing mints ZERO spans on every path.  The
-        depth-1 (legacy synchronous) path additionally measures no
-        segments — its no-extra-device-syncs contract; the pipelined
-        path gets stage intervals for free (the stages block per leg
-        anyway), so its counters MAY advance, but spans still must
-        not."""
+        """Disabled tracing mints ZERO spans.  The pipeline stages
+        time their legs anyway (each blocks per leg), so the segment
+        counters advance, but no span object is made."""
         from ceph_tpu.osd.tpu_dispatch import TpuDispatcher
         tracer = SpanCollector()          # disabled
-        disp = TpuDispatcher(tracer=tracer, pipeline_depth=1)
+        disp = TpuDispatcher(tracer=tracer)
         try:
             out = disp.encode(_XorCodec(),
                               np.zeros((1, 2, 4), dtype=np.uint8))
             assert out.shape == (1, 2, 4)
             assert tracer.dump() == []
-            assert disp.perf.dump()["l_tpu_compute"]["avgcount"] == 0
-        finally:
-            disp.shutdown()
-        disp = TpuDispatcher(tracer=tracer)   # pipelined default
-        try:
-            out = disp.encode(_XorCodec(),
-                              np.zeros((1, 2, 4), dtype=np.uint8))
-            assert out.shape == (1, 2, 4)
-            assert tracer.dump() == []        # still no span objects
+            assert disp.perf.dump()["l_tpu_compute"]["avgcount"] == 1
         finally:
             disp.shutdown()
 
